@@ -13,8 +13,9 @@ Engines (BENCH_ENGINE): "fused" (default) = the one-invocation-per-round
 kernel with transpose routing (ops/fused.py); "serial" = the per-message
 step scan + grouped router (cluster.py), the conformance-exact path.
 
-`vs_baseline` is measured against the BASELINE.md target of 1M
-groups*ticks/s (the reference publishes no numbers; see BASELINE.md).
+`vs_baseline` is measured against the BASELINE.json target of 1M
+groups*ticks/s (the reference publishes no numbers; see README.md, "North
+star"). The fleet is built as chip_smoke.py builds it; a failed run fails.
 """
 
 from __future__ import annotations
@@ -27,12 +28,8 @@ from raft_tpu import config
 
 import jax
 
-from raft_tpu.utils.compile_cache import cache_dir_from_env, enable_persistent_cache
+from raft_tpu.utils.compile_cache import enable_persistent_cache
 
-# RAFT_TPU_COMPILE_CACHE=<dir> opts any backend (CPU included) into the
-# persistent compilation cache; non-CPU backends keep it on by default
-if cache_dir_from_env() or jax.default_backend() != "cpu":
-    enable_persistent_cache()
 import jax.numpy as jnp
 
 
@@ -152,7 +149,10 @@ def run_serial(n_groups, n_voters, n_iters, block):
 
 
 def main():
-    platform = jax.devices()[0].platform
+    dev = jax.devices()[0]
+    platform = dev.platform
+    if platform != "cpu":
+        enable_persistent_cache()
     engine = os.environ.get("BENCH_ENGINE", "fused")
     # The headline shape is BASELINE.json config 5's 1M groups, held
     # resident as 16 blocks of 64k groups (scheduler.BlockedFusedCluster):
@@ -172,28 +172,11 @@ def main():
 
     from raft_tpu.utils.profiling import env_trace_dir, trace
 
-    fallback = False
     with trace(env_trace_dir()):
         if engine == "fused":
-            try:
-                dt, compile_s, n_leaders, commits, met, probe = run_fused(
-                    n_groups, n_voters, n_iters, block, block_groups
-                )
-            except Exception as e:  # noqa: BLE001 — still print a record
-                if n_groups <= block_groups:
-                    raise
-                import sys, traceback
-
-                traceback.print_exc(file=sys.stderr)
-                print(
-                    f"# {n_groups}-group run failed ({type(e).__name__}); "
-                    f"falling back to one {block_groups}-group block",
-                    file=sys.stderr,
-                )
-                fallback, n_groups = True, block_groups
-                dt, compile_s, n_leaders, commits, met, probe = run_fused(
-                    n_groups, n_voters, n_iters, block, block_groups
-                )
+            dt, compile_s, n_leaders, commits, met, probe = run_fused(
+                n_groups, n_voters, n_iters, block, block_groups
+            )
         else:
             dt, compile_s, n_leaders, commits, met, probe = run_serial(
                 n_groups, n_voters, n_iters, block
@@ -206,7 +189,6 @@ def main():
         "groups": n_groups,
         "block_groups": block_groups,
         "resident_blocks": -(-n_groups // block_groups),
-        "fallback": fallback,
         "voters": n_voters,
         "leaders_elected": n_leaders,
         "commits_per_group_round": round(
@@ -216,6 +198,8 @@ def main():
         "block": block,
         "compile_s": round(compile_s, 1),
         "platform": platform,
+        "device_kind": dev.device_kind,
+        "device_count": len(jax.devices()),
     }
     if probe is not None:
         extra.update(probe)

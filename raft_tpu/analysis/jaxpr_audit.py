@@ -14,9 +14,9 @@ ad-hoc in whichever test first needed them (see ISSUE/README):
   can't prove elision).
 - **donation** — a donating twin's lowering must carry an input-output
   alias for every donated carry leaf; a donated leaf that LOST its alias
-  (jax lowers it with a "donated buffers were not usable" warning and no
-  `tf.aliasing_output` attribute) is a silent HBM doubling. The copying
-  twin must alias nothing.
+  (read from the lowered @main signature: a `jax.buffer_donor` argument
+  that no unpaired output of its exact type can take over) is a silent
+  HBM doubling. The copying twin must alias nothing.
 - **dtype discipline** — under RAFT_TPU_DIET=1 the packed carry columns
   (uint16 indexes/terms, int8 ids, int16 sizes, uint8/16/32 bitsets) must
   ride the scan carry / pallas operands in their packed dtypes. The
@@ -54,7 +54,7 @@ import dataclasses
 import functools
 import inspect
 import re
-import warnings
+from collections import Counter
 
 import jax
 
@@ -345,23 +345,45 @@ def check_paged_roundtrip(rec_a, rec_b) -> list:
 # donation (lowered-HLO level)
 
 
-def lower_text_and_warnings(rec) -> tuple[str, list]:
+_TENSOR_RE = re.compile(r"tensor<[^>]*>")
+
+
+def unusable_donors(text: str) -> dict:
+    """{flat arg position: tensor type} of donated arguments that no
+    output can take over, read from the lowered @main signature. jax
+    marks a donated argument it paired with an output
+    `tf.aliasing_output = k`, and one it left for XLA to pair
+    `jax.buffer_donor`; a donor is usable only while an unpaired output
+    of its exact type remains."""
+    attrs = _main_arg_attrs(text)
+    m = re.search(
+        r"func\.func public @main\(.*?\)\s*->\s*(.*?)\s*\{\s*$",
+        text, re.S | re.M,
+    )
+    if attrs is None or m is None:
+        return {}
+    results = _TENSOR_RE.findall(m.group(1))
+    claimed = {
+        int(k) for k in re.findall(r"tf\.aliasing_output = (\d+)", text)
+    }
+    free = Counter(t for i, t in enumerate(results) if i not in claimed)
+    bad = {}
+    for i, a in sorted(attrs.items()):
+        if "jax.buffer_donor" not in a:
+            continue
+        t = _TENSOR_RE.search(a).group(0)
+        if free[t]:
+            free[t] -= 1
+        else:
+            bad[i] = t
+    return bad
+
+
+def lower_text(rec) -> str:
     """Lower the record's jit twin for its example args; returns the
-    StableHLO text and any 'donated buffers were not usable' warnings
-    jax emitted during lowering (each one is a donated leaf that lost
-    its alias)."""
-    jit = rec["jit"]
+    StableHLO text."""
     kwargs = {**rec.get("static", {}), **rec.get("kwargs", {})}
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        lowered = jit.lower(*rec["args"], **kwargs)
-    text = lowered.as_text()
-    dropped = [
-        str(w.message)
-        for w in caught
-        if "donated buffers were not usable" in str(w.message).lower()
-    ]
-    return text, dropped
+    return rec["jit"].lower(*rec["args"], **kwargs).as_text()
 
 
 def donated_leaf_count(rec) -> int:
@@ -376,22 +398,27 @@ def donated_leaf_count(rec) -> int:
     return len(jax.tree.leaves(donated))
 
 
-def check_donation(name, rec, lowered=None) -> list:
+def check_donation(name, rec, text: str | None = None) -> list:
     """Donating twin: every donated carry leaf aliases an output (count
-    `tf.aliasing_output`/`jax.buffer_donor` markers, catch jax's
-    unusable-donation warning). Copying twin: aliases nothing.
-    ``lowered`` lets the caller share one (text, dropped) lowering with
-    the escape check."""
-    text, dropped = lowered if lowered is not None \
-        else lower_text_and_warnings(rec)
-    aliased = text.count("tf.aliasing_output") + text.count("jax.buffer_donor")
+    `tf.aliasing_output`/`jax.buffer_donor` markers, less the donors no
+    output can take). Copying twin: aliases nothing. ``text`` lets the
+    caller share one lowering with the escape check."""
+    if text is None:
+        text = lower_text(rec)
+    dropped = unusable_donors(text)
+    aliased = (
+        text.count("tf.aliasing_output") + text.count("jax.buffer_donor")
+        - len(dropped)
+    )
     out = []
     if rec["donate"]:
         expected = donated_leaf_count(rec)
         if dropped:
+            i, t = next(iter(dropped.items()))
             out.append(Finding(name, "donation", (
-                f"{len(dropped)} donated leaf group(s) lost their alias "
-                f"(silent HBM doubling): {dropped[0]}"
+                f"{len(dropped)} donated leaf/leaves lost their alias "
+                f"(silent HBM doubling): flat arg {i} ({t}) has no "
+                "output of its type left to take it"
             )))
         if aliased < expected:
             out.append(Finding(name, "donation", (
@@ -469,7 +496,8 @@ def check_donation_escape(name, rec, text: str | None = None) -> list:
     the worst of both. Names the leaf via the record's example pytrees
     so the finding points at a column, not a position."""
     if text is None:
-        text, _ = lower_text_and_warnings(rec)
+        text = lower_text(rec)
+    bad = unusable_donors(text)
     names, donated = flat_arg_names(rec)
     if not donated:
         return []
@@ -480,13 +508,15 @@ def check_donation_escape(name, rec, text: str | None = None) -> list:
             "program — escape analysis can't run (lowering layout drift)"
         ))]
 
-    def aliased(a: str) -> bool:
-        return "tf.aliasing_output" in a or "jax.buffer_donor" in a
+    def aliased(i: int, a: str) -> bool:
+        return "tf.aliasing_output" in a or (
+            "jax.buffer_donor" in a and i not in bad
+        )
 
     if len(attrs) != len(names):
         # the lowering pruned unused args: flat positions shifted, so
         # degrade to count-level accounting rather than misname leaves
-        n_aliased = sum(1 for a in attrs.values() if aliased(a))
+        n_aliased = sum(1 for i, a in attrs.items() if aliased(i, a))
         missing = len(donated) - n_aliased
         if missing > 0:
             return [Finding(name, "escape", (
@@ -497,7 +527,7 @@ def check_donation_escape(name, rec, text: str | None = None) -> list:
         return []
     out = []
     for i in sorted(donated):
-        if not aliased(attrs.get(i, "")):
+        if not aliased(i, attrs.get(i, "")):
             leaf = names[i] if i < len(names) else f"flat arg {i}"
             out.append(Finding(name, "escape", (
                 f"donated leaf '{leaf}' (flat arg {i}) has no "
@@ -540,11 +570,11 @@ def audit_record(rec, *, expect_on=None, diet: bool = False) -> list:
     if want("carry"):
         out += check_carry_stability(name, jaxpr, rec)
     if rec.get("jit") is not None and (want("donation") or want("escape")):
-        lowered = lower_text_and_warnings(rec)
+        lowered = lower_text(rec)
         if want("donation"):
-            out += check_donation(name, rec, lowered=lowered)
+            out += check_donation(name, rec, text=lowered)
         if want("escape") and rec.get("donate"):
-            out += check_donation_escape(name, rec, text=lowered[0])
+            out += check_donation_escape(name, rec, text=lowered)
     return out
 
 

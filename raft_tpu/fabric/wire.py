@@ -221,8 +221,8 @@ _NP_ORDER = ("chan", "cell") + SCALAR_FIELDS + ENT_FIELDS
 
 def fabric_codec() -> str:
     """RAFT_TPU_FABRIC_CODEC: "pb" (byte-exact raftpb frames via the
-    native codec) or "np" (raw columnar, diet-capable). Unset/empty =
-    auto: pb when the native library loads, np otherwise."""
+    native codec, the default when unset) or "np" (raw columnar,
+    diet-capable)."""
     return config.env_str("RAFT_TPU_FABRIC_CODEC", default="")
 
 
@@ -231,12 +231,6 @@ def fabric_diet_enabled() -> bool:
     diet's sub-int16 bounds (requires RAFT_TPU_DIET=1 and the np codec;
     default OFF)."""
     return config.env_flag("RAFT_TPU_FABRIC_DIET", default=False)
-
-
-def _native_available() -> bool:
-    from raft_tpu.runtime.native import _load
-
-    return _load() is not None
 
 
 class FabricWire:
@@ -249,13 +243,9 @@ class FabricWire:
         self.e = int(n_ents)
         self.counters = counters
         self.diet = fabric_diet_enabled()
-        name = codec or fabric_codec() or ("pb" if _native_available() else "np")
+        name = codec or fabric_codec() or "pb"
         if name not in ("pb", "np"):
             raise ValueError(f"RAFT_TPU_FABRIC_CODEC must be pb|np, got {name!r}")
-        if name == "pb" and not _native_available():
-            raise RuntimeError(
-                "RAFT_TPU_FABRIC_CODEC=pb needs the native raftpb library"
-            )
         if self.diet:
             if name != "np":
                 raise RuntimeError(

@@ -533,33 +533,6 @@ class JsonlWriter:
 
 
 # --------------------------------------------------------------------------
-# engine events (host plane)
-
-# Process-wide host counters for round-engine lifecycle events. Compiled
-# kernels cannot log, so the pallas->XLA engine fallback (ops/fused.py
-# FusedCluster._run_pallas and the blocked/sharded schedulers) reports
-# here: the counter always bumps, the WARNING logs once per distinct key
-# so a fleet of clusters sharing one unlowerable Shape does not spam.
-ENGINE_EVENTS = HostCounters()
-_FALLBACK_LOGGED: set = set()
-
-
-def record_engine_fallback(key: str, err) -> None:
-    """Record one pallas->XLA engine fallback on the host plane."""
-    from raft_tpu.logging import get_logger
-
-    ENGINE_EVENTS.inc("engine_pallas_fallback")
-    if key not in _FALLBACK_LOGGED:
-        _FALLBACK_LOGGED.add(key)
-        get_logger().warning(
-            "pallas engine fell back to XLA for %s: %s: %s",
-            key,
-            type(err).__name__ if isinstance(err, BaseException) else "error",
-            err,
-        )
-
-
-# --------------------------------------------------------------------------
 # paged entry log (host plane)
 
 # Process-wide mirror of the PagedLog device accumulators. Updated by

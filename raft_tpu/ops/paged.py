@@ -447,7 +447,7 @@ def split_state(state: RaftState, plan: PagePlan, segs: int = 1):
 def resegment(state: RaftState, paged: PagedLog, old_segs: int,
               new_segs: int):
     """Re-key the pool/page-table from one allocation segmentation to
-    another (engine fallback, sharded adoption of a mono carry): read
+    another (sharded adoption of a mono carry): read
     the full window under the old segmentation, re-split under the new.
     Page ids are local to the sub-pool the allocator saw, so tables
     written under one segmentation must never be read under another —

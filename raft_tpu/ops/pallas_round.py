@@ -53,18 +53,18 @@ Contract vs ops/fused.py fused_rounds:
   K=1 (documented in README, asserted by tests) — same events, K-1 fewer
   fused round-trips forgone while the flight recorder is on.
 - Donation composes like fused_rounds: `_pallas_rounds_jit` donates the
-  (state, fab, metrics, chaos) carry and must run under the jax 0.4.37
-  persistent-cache fence (ops/fused.py _no_persistent_cache);
-  `_pallas_rounds_nodonate_jit` is the copying twin.
+  (state, fab, metrics, chaos) carry; `_pallas_rounds_nodonate_jit` is
+  the copying twin.
 - Straddle sharding is NOT supported (groups must be shard- and
-  tile-resident); parallel/sharded.py routes straddle configs to XLA.
+  tile-resident); parallel/sharded.py raises on pallas + straddle.
 
 Engine selection lives in resolve_engine (RAFT_TPU_ENGINE env or the
 `engine=` kwarg on FusedCluster / BlockedFusedCluster /
-ShardedFusedCluster). Dispatchers degrade gracefully: if Mosaic fails to
-lower for a given Shape, they log once via the metrics host plane
-(metrics/host.py record_engine_fallback) and fall back to the XLA path
-rather than erroring — see FusedCluster._run_pallas.
+ShardedFusedCluster). There is no fallback: if Mosaic refuses the kernel
+the dispatch raises with the compiler's message. On TPU today it does —
+the round body's vector reshapes and rolls (e.g. `_route_shift_field`'s
+1-D `jnp.roll`) are outside what Mosaic lowers, so this engine runs in
+interpret mode only (ROADMAP C3).
 
 Autotuner: `autotune_plan` sweeps (tile, K) jointly at first dispatch
 (TPU only; sweeping interpret mode would time the interpreter), caching
@@ -147,7 +147,7 @@ _CH_PROBE = ("base_committed", "reelect_round", "recommit_round")
 
 class TileError(ValueError):
     """A lane tile that violates the group-alignment invariant. This is a
-    configuration error, never swallowed by the engine fallback."""
+    configuration error."""
 
 
 def resolve_engine(engine: str | None = None) -> str:
@@ -160,12 +160,11 @@ def resolve_engine(engine: str | None = None) -> str:
 
 
 def default_interpret() -> bool:
-    """Interpret-mode default: RAFT_TPU_PALLAS_INTERPRET if set, else
-    everything but real TPU hardware interprets (Mosaic is TPU-only)."""
-    env = config.env_raw("RAFT_TPU_PALLAS_INTERPRET")
-    if env not in (None, ""):
-        return env not in ("0", "off")
-    return jax.default_backend() != "tpu"
+    """Interpret mode is chosen, never inferred from the backend:
+    RAFT_TPU_PALLAS_INTERPRET=1 (the CPU test rig sets it) or an explicit
+    interpret= argument. Unset, kernels lower through Mosaic, and a
+    backend that cannot run them fails loudly."""
+    return config.env_flag("RAFT_TPU_PALLAS_INTERPRET", default=False)
 
 
 def autotune_enabled() -> bool:
@@ -254,18 +253,6 @@ def check_tile(n: int, v: int, tile_lanes: int) -> None:
         raise TileError(
             f"tile_lanes={tile_lanes} does not divide the lane count "
             f"n={n}: the tile grid must cover the batch exactly"
-        )
-
-
-def maybe_force_fail() -> None:
-    """Test hook standing in for a Mosaic lowering failure so the engine
-    fallback path is exercisable on any backend. Checked both at trace
-    time (pallas_rounds) and at dispatch time (FusedCluster._run_pallas,
-    the sharded stepper) — a warm jit cache skips tracing entirely, and
-    the fallback must still fire."""
-    if config.env_flag("RAFT_TPU_PALLAS_FORCE_FAIL", default=False):
-        raise RuntimeError(
-            "pallas lowering forced to fail (RAFT_TPU_PALLAS_FORCE_FAIL)"
         )
 
 
@@ -444,7 +431,6 @@ def pallas_rounds(
     structural: page_out . page_in is value-identity on scrubbed
     windows, so paging granularity is invisible to the trajectory (only
     the faults/dirty/skipped counters differ in cadence)."""
-    maybe_force_fail()
     validate_round_plan(rounds_per_call)
     # diet-v2: a packed carry (bitset masks + u16 indexes) rides the
     # HBM<->VMEM boundary packed — every boundary cast below replays the
@@ -902,9 +888,7 @@ _PALLAS_STATIC = (
     "paged_inkernel",
 )
 
-# donating/copying twins, mirroring ops/fused.py: the donating twin MUST be
-# dispatched under fused._no_persistent_cache (jax 0.4.37 deserializes
-# donating executables that mis-execute; see fused.py)
+# donating/copying twins, mirroring ops/fused.py
 _pallas_rounds_jit = jax.jit(
     pallas_rounds,
     static_argnames=_PALLAS_STATIC,
@@ -920,6 +904,5 @@ def round_jit_twin(donate: bool):
     """The jitted round program for one donation mode — the single
     selection point the static auditor, the resource ledger and the
     bench lowerings share, so a twin swap can never happen in one of
-    them only (the dispatch path in ops/fused.py keeps its explicit
-    pair: the donating twin rides the _no_persistent_cache fence)."""
+    them only — FusedCluster._run_pallas dispatches through it too)."""
     return _pallas_rounds_jit if donate else _pallas_rounds_nodonate_jit

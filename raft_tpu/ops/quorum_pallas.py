@@ -22,9 +22,8 @@ trip. With the relayout eliminated the old argument for the XLA default is
 obsolete, and `joint_committed_dispatch` routes joint configs to THIS
 kernel by default (RAFT_TPU_QUORUM_PALLAS=0 restores XLA; outputs are
 bit-identical either way, tests/test_quorum_pallas.py). A Mosaic lowering
-failure degrades to the XLA path with a once-logged engine event
-(metrics/host.py record_engine_fallback), mirroring the full-round
-engine's posture (ops/pallas_round.py).
+failure raises; interpret mode is only ever chosen explicitly
+(interpret=True, or RAFT_TPU_PALLAS_INTERPRET=1 on the CPU test rig).
 
 For callers that can keep the quorum operands voter-major IN THEIR CARRY
 (amortizing one layout change over many reductions), `pack_voter_major` +
@@ -152,12 +151,10 @@ def _out_specs(n_pad):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def committed_pallas(match, mask, interpret: bool | None = None):
+def committed_pallas(match, mask, interpret: bool = False):
     """majority_committed on the Pallas path. match/mask: [N, V] -> [N],
     read lane-major (native layout, zero relayout)."""
     n, v = match.shape
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
     n_pad = -(-n // _TILE) * _TILE
     grid, out_spec = _out_specs(n_pad)
     spec = pl.BlockSpec(
@@ -175,12 +172,10 @@ def committed_pallas(match, mask, interpret: bool | None = None):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def joint_committed_pallas(match, mask_in, mask_out, interpret: bool | None = None):
+def joint_committed_pallas(match, mask_in, mask_out, interpret: bool = False):
     """JointConfig.CommittedIndex fused: both halves + min in one pass,
     operands read lane-major (native layout, zero relayout)."""
     n, v = match.shape
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
     n_pad = -(-n // _TILE) * _TILE
     grid, out_spec = _out_specs(n_pad)
     spec = pl.BlockSpec(
@@ -204,14 +199,12 @@ def joint_committed_pallas(match, mask_in, mask_out, interpret: bool | None = No
 @functools.partial(jax.jit, static_argnames=("v", "n", "interpret"))
 def joint_committed_packed(
     match_vm, in_vm, out_vm, *, v: int, n: int,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """JointConfig.CommittedIndex over pre-packed voter-major operands
     (pack_voter_major): [VPAD, N_pad] x3 -> [n]. Zero per-call layout work
     — the fast path for carries that store the operands packed."""
     n_pad = match_vm.shape[1]
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
     grid, out_spec = _out_specs(n_pad)
     spec = pl.BlockSpec(
         (_VPAD, _TILE), lambda i: (0, i), memory_space=pltpu.VMEM
@@ -235,8 +228,9 @@ def joint_committed_dispatch(
     that the per-operand voter-major relayout is gone (module doc):
     engine kwarg > RAFT_TPU_QUORUM_PALLAS env (default 1) > pallas.
     RAFT_TPU_QUORUM_PALLAS=0 restores the XLA path. Outputs are
-    bit-identical either way (tests/test_quorum_pallas.py). A pallas
-    lowering failure logs one engine event and degrades to XLA."""
+    bit-identical either way (tests/test_quorum_pallas.py). interpret=None
+    reads RAFT_TPU_PALLAS_INTERPRET (default off); a kernel the backend
+    cannot run raises."""
     e = engine
     if e is None:
         e = (
@@ -248,18 +242,8 @@ def joint_committed_dispatch(
         raise ValueError(f"unknown engine {e!r}: expected 'xla' or 'pallas'")
     from raft_tpu.ops.quorum import joint_committed
 
-    if e == "pallas":
-        try:
-            return joint_committed_pallas(
-                match, mask_in, mask_out, interpret=interpret
-            )
-        except Exception as err:
-            from raft_tpu.metrics.host import record_engine_fallback
-
-            n, v = match.shape
-            record_engine_fallback(
-                f"joint_committed_dispatch(n={n}, v={v}, "
-                f"backend={jax.default_backend()})",
-                err,
-            )
-    return joint_committed(match, mask_in, mask_out)
+    if e == "xla":
+        return joint_committed(match, mask_in, mask_out)
+    if interpret is None:
+        interpret = config.env_flag("RAFT_TPU_PALLAS_INTERPRET", default=False)
+    return joint_committed_pallas(match, mask_in, mask_out, interpret=interpret)

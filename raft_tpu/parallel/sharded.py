@@ -22,10 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from raft_tpu import config
 from raft_tpu.cluster import (
@@ -36,7 +33,6 @@ from raft_tpu.cluster import (
     _bytes_between,
 )
 from raft_tpu.messages import MsgBatch, empty_batch
-from raft_tpu.ops.fused import _no_persistent_cache
 from raft_tpu.ops.fused import donation_enabled as _donation_enabled
 from raft_tpu.ops import log as lg
 from raft_tpu.ops import step as stepmod
@@ -256,10 +252,9 @@ class ShardedCluster(Cluster):
     def _do_round(self, do_tick: bool):
         inbox = jax.tree.map(jnp.asarray, self._pending)
         fn = self._sharded_round(do_tick)
-        with _no_persistent_cache(self._donate):
-            self.state, nxt, dropped = fn(
-                self.state, inbox, self.group_of, self.lane_of
-            )
+        self.state, nxt, dropped = fn(
+            self.state, inbox, self.group_of, self.lane_of
+        )
         self._pending = jax.tree.map(lambda x: np.array(x), nxt)
         self.dropped += int(dropped)
 
@@ -280,12 +275,9 @@ class ShardedCluster(Cluster):
                     return (st, nxt, drops + d), None
 
                 # shard-local (axis-varying) accumulator for dropped counts
-                if hasattr(jax.lax, "pcast"):
-                    zero = jax.lax.pcast(
-                        jnp.zeros((), I32), ("groups",), to="varying"
-                    )
-                else:  # jax < 0.8: experimental shard_map needs no vma cast
-                    zero = jnp.zeros((), I32)
+                zero = jax.lax.pcast(
+                    jnp.zeros((), I32), ("groups",), to="varying"
+                )
                 (state, inbox, dropped), _ = jax.lax.scan(
                     body, (state, inbox, zero), length=n_rounds,
                 )
@@ -300,10 +292,9 @@ class ShardedCluster(Cluster):
         """`rounds` sharded rounds in one dispatch."""
         fn = self._sharded_rounds(do_tick, rounds)
         inbox = jax.tree.map(jnp.asarray, self._pending)
-        with _no_persistent_cache(self._donate):
-            self.state, nxt, dropped = fn(
-                self.state, inbox, self.group_of, self.lane_of
-            )
+        self.state, nxt, dropped = fn(
+            self.state, inbox, self.group_of, self.lane_of
+        )
         self._pending = jax.tree.map(lambda x: np.array(x), nxt)
         self.dropped += int(dropped)
 
@@ -317,10 +308,9 @@ class ShardedCluster(Cluster):
         )
         total_dropped = jnp.zeros((), I32)
         for i in range(n_rounds):
-            with _no_persistent_cache(self._donate):
-                state, pending, dropped = fn(
-                    state, pending, self.group_of, self.lane_of
-                )
+            state, pending, dropped = fn(
+                state, pending, self.group_of, self.lane_of
+            )
             total_dropped = total_dropped + dropped
             if i % 8 == 7:  # bound in-flight executions (memory pressure)
                 jax.block_until_ready(state.term)
@@ -378,19 +368,11 @@ class ShardedFusedCluster:
         if straddle and self.inner.engine == "pallas":
             # the pallas kernel's router is strictly tile-local; the halo
             # ppermute of the straddle path has no kernel analog
-            if cfg.get("engine"):
-                raise ValueError(
-                    "engine='pallas' does not support straddle=True: the "
-                    "in-kernel router never crosses a lane tile, let alone "
-                    "a shard boundary (drop straddle or use engine='xla')"
-                )
-            from raft_tpu.metrics.host import record_engine_fallback
-
-            record_engine_fallback(
-                f"ShardedFusedCluster(straddle, n={n}, v={n_voters})",
-                RuntimeError("straddle unsupported on the pallas engine"),
+            raise ValueError(
+                "engine='pallas' does not support straddle=True: the "
+                "in-kernel router never crosses a lane tile, let alone "
+                "a shard boundary (drop straddle or use engine='xla')"
             )
-            self.inner.engine = "xla"
         self.mesh, self.lane_sharding, shard_lanes = make_group_mesh(devices, n)
         self.inner.state = jax.tree.map(shard_lanes, self.inner.state)
         self.inner.fab = jax.tree.map(shard_lanes, self.inner.fab)
@@ -534,8 +516,7 @@ class ShardedFusedCluster:
         """Megakernel K for the per-shard pallas grid. Explicit ctor
         rounds_per_call > RAFT_TPU_PALLAS_ROUNDS env > 1; no joint sweep
         here for the same reason as the tile (timing the collective
-        program times the mesh, not the kernel). Validated up front —
-        config errors, never engine fallbacks."""
+        program times the mesh, not the kernel). Validated up front."""
         if self._shard_rounds is not None:
             return self._shard_rounds
         from raft_tpu.ops import pallas_round as plr
@@ -730,7 +711,7 @@ class ShardedFusedCluster:
             mesh=self.mesh,
             in_specs=tuple(in_specs),
             out_specs=tuple(out_specs),
-            **({"check_rep": False} if extras else {}),
+            **({"check_vma": False} if extras else {}),
         )
         donate = ()
         if self._donate:
@@ -830,46 +811,18 @@ class ShardedFusedCluster:
         tile = interp = None
         rpc = 1
         if engine == "pallas":
-            # K/unroll validation is a config error and must propagate —
-            # resolve it OUTSIDE the fallback try
             rpc = self._resolve_shard_rounds()
-            # tile/force-fail problems surface here, before the carry is
-            # handed to a donating dispatch (TileErrors still propagate)
-            try:
-                plr.maybe_force_fail()
-                tile = self._resolve_shard_tile()
-                interp = plr.default_interpret()
-            except plr.TileError:
-                raise
-            except Exception as e:
-                self._fall_back(e)
-                engine = "xla"
-                rpc = 1
+            tile = self._resolve_shard_tile()
+            interp = plr.default_interpret()
         key = (engine, rounds, do_tick, auto_propose, auto_compact_lag, rpc)
         if key not in self._cache:
             self._cache[key] = self._build_stepper(
                 engine, rounds, do_tick, auto_propose, auto_compact_lag,
                 rpc, tile, interp,
             )
-        try:
-            with _no_persistent_cache(self._donate):
-                res = self._cache[key](
-                    self.inner.state, self.inner.fab, ops, self.inner.mute,
-                    *extras,
-                )
-        except Exception as e:
-            if engine != "pallas" or isinstance(e, plr.TileError):
-                raise
-            # Mosaic lowering fails at trace/compile time, before any
-            # donated buffer is consumed: the carry is intact, redrive the
-            # same rounds on the XLA stepper
-            self._fall_back(e)
-            return self.run(
-                rounds, ops=ops, do_tick=do_tick,
-                auto_propose=auto_propose,
-                auto_compact_lag=auto_compact_lag,
-                wal=wal, egress=egress, trace=trace,
-            )
+        res = self._cache[key](
+            self.inner.state, self.inner.fab, ops, self.inner.mute, *extras,
+        )
         self.inner.state, self.inner.fab = res[0], res[1]
         j = 2
         if has_met:
@@ -899,36 +852,6 @@ class ShardedFusedCluster:
             trace.push(self.inner.trace)
             if self._donate:
                 self.inner._trace_pending = trace
-
-    def _fall_back(self, err):
-        """Log the pallas -> XLA engine fallback once via the metrics host
-        plane and flip the inner engine (sticky for this cluster)."""
-        from raft_tpu.metrics.host import record_engine_fallback
-
-        record_engine_fallback(
-            f"ShardedFusedCluster(n={self.g * self.v}, v={self.v}, "
-            f"shards={self.n_shards}, backend={jax.default_backend()})",
-            err,
-        )
-        self.inner.engine = "xla"
-        if (
-            self.inner.paged is not None
-            and self.inner._paged_segs != self.n_shards
-        ):
-            # the in-kernel pallas grid allocated per (shard, tile); the
-            # XLA twin allocates per shard — rewrite the page ids before
-            # the next dispatch
-            from raft_tpu.ops import paged as pgmod
-
-            st, pgl = pgmod.resegment(
-                self.inner.state, self.inner.paged,
-                self.inner._paged_segs, self.n_shards,
-            )
-            self.inner.state = jax.tree.map(self._shard_lanes, st)
-            self.inner.paged = jax.tree.map(
-                lambda x: jax.device_put(x, self.lane_sharding), pgl
-            )
-            self.inner._paged_segs = self.n_shards
 
     def set_chaos(self, **cols):
         """Install chaos columns, then re-shard them over the mesh (the

@@ -80,14 +80,10 @@ class HostBridge:
 
         codec = None
         if self.wire and msgs:
-            # lazy: wire mode needs the native library; hosts without it use
-            # in-memory delivery — checked ONCE up front so a missing library
-            # can never abort a delivery batch partway through
+            # wire mode frames every message through the native raftpb codec
             from raft_tpu.runtime import codec as _codec
-            from raft_tpu.runtime.native import _load
 
-            if _load() is not None:
-                codec = _codec
+            codec = _codec
 
         log = get_logger()
         # group per destination host, preserving per-host order

@@ -20,8 +20,6 @@ _N_SCALARS = 11  # see raftpb_codec.cc scalar slots
 
 def _lib():
     lib = _load()
-    if lib is None:
-        raise RuntimeError("native library unavailable")
     if not getattr(lib, "_codec_bound", False):
         c = ctypes.c_void_p  # keep signatures loose; numpy buffers below
         lib.msg_marshal.restype = ctypes.c_int64

@@ -2,14 +2,16 @@
 
 The compute path is JAX/XLA; the runtime around it — here the entry-payload
 arena backing the device's columnar log — is native C++ (see
-native/payload_store.cc). The library is built on demand with the in-image
-g++ (no pip deps); when compilation is impossible the callers fall back to
-the pure-Python `EntryStore`.
+native/payload_store.cc). The library is built on demand from the tracked
+sources with the in-image g++ (no pip deps). A library is trusted only if
+it was built here from the sources as they are now: the build records the
+sources' sha256 next to it, and a mismatch rebuilds. A failed build raises.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,32 +20,54 @@ import numpy as np
 
 _DIR = os.path.join(os.path.dirname(__file__), "..", "native")
 _SO = os.path.join(_DIR, "libraft_tpu_native.so")
+_STAMP = _SO + ".sha256"
+_SRCS = [os.path.join(_DIR, f) for f in ("payload_store.cc", "raftpb_codec.cc")]
 _lock = threading.Lock()
 _lib = None
-_build_failed = False
+
+
+def _sources_digest() -> str:
+    h = hashlib.sha256()
+    for src in _SRCS:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def build(force: bool = False) -> None:
+    """Build the payload library from the tracked sources (`force`: even
+    when the stamp matches). Raises RuntimeError with make's output on a
+    failed build."""
+    digest = _sources_digest()
+    if not force and os.path.exists(_SO) and os.path.exists(_STAMP):
+        with open(_STAMP) as f:
+            if f.read().strip() == digest:
+                return
+    # build under a private name, then rename: a concurrent loader (test
+    # workers share the tree) never maps a half-written library
+    tmp = f"{os.path.basename(_SO)}.{os.getpid()}.tmp"
+    r = subprocess.run(
+        ["make", "-s", "-B", f"SO={tmp}", tmp],
+        cwd=_DIR, capture_output=True, text=True,
+    )
+    if r.returncode != 0:
+        raise RuntimeError(
+            f"native build failed (make exit {r.returncode}):\n"
+            f"{r.stdout}{r.stderr}"
+        )
+    os.replace(os.path.join(_DIR, tmp), _SO)
+    with open(_STAMP + f".{os.getpid()}.tmp", "w") as f:
+        f.write(digest + "\n")
+    os.replace(_STAMP + f".{os.getpid()}.tmp", _STAMP)
 
 
 def _load():
-    global _lib, _build_failed
+    global _lib
     with _lock:
-        if _lib is not None or _build_failed:
+        if _lib is not None:
             return _lib
-        try:
-            srcs = [
-                os.path.join(_DIR, "payload_store.cc"),
-                os.path.join(_DIR, "raftpb_codec.cc"),
-            ]
-            stale = not os.path.exists(_SO) or any(
-                os.path.getmtime(_SO) < os.path.getmtime(s) for s in srcs
-            )
-            if stale:
-                subprocess.run(
-                    ["make", "-s"], cwd=_DIR, check=True, capture_output=True
-                )
-            lib = ctypes.CDLL(_SO)
-        except Exception:
-            _build_failed = True
-            return None
+        build()
+        lib = ctypes.CDLL(_SO)
         c = ctypes
         lib.ps_new.restype = c.c_void_p
         lib.ps_new.argtypes = [c.c_int32]
@@ -83,18 +107,12 @@ def _load():
         return _lib
 
 
-def native_available() -> bool:
-    return _load() is not None
-
-
 class NativePayloadStore:
     """Drop-in for api.rawnode.EntryStore backed by the C++ arena. Snapshots
     (rare, structured) stay Python-side."""
 
     def __init__(self, n_lanes: int):
         lib = _load()
-        if lib is None:
-            raise RuntimeError("native library unavailable")
         self._lib = lib
         self._h = ctypes.c_void_p(lib.ps_new(n_lanes))
         self._snap = [None] * n_lanes
@@ -160,10 +178,6 @@ class NativePayloadStore:
             cap = max(cap * 2, int(-r))
 
 
-def make_payload_store(n_lanes: int):
-    """Native store when buildable, else the pure-Python EntryStore."""
-    if native_available():
-        return NativePayloadStore(n_lanes)
-    from raft_tpu.api.rawnode import EntryStore
-
-    return EntryStore(n_lanes)
+def make_payload_store(n_lanes: int) -> NativePayloadStore:
+    """The C++ payload arena (built on first use; a failed build raises)."""
+    return NativePayloadStore(n_lanes)

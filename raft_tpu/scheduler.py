@@ -200,9 +200,7 @@ class BlockedFusedCluster:
     Engine selection (`engine=` / `RAFT_TPU_ENGINE`, ops/pallas_round.py)
     flows through **cfg to every resident block's FusedCluster: all K
     blocks share one pallas kernel signature exactly like they share the
-    XLA one, and a per-block fallback flips only that block (the shared
-    compile cache makes the first block's failure everyone's fallback in
-    practice).
+    XLA one.
 
     round_chunk: rounds per dispatch in the round-major sweep (default 1 =
     strict round-major interleave; larger values amortize per-dispatch host

@@ -459,7 +459,6 @@ class TierEngine:
 
         from raft_tpu.ops import paged as pgmod
         from raft_tpu.ops.fused import (
-            _no_persistent_cache,
             pack_fabric,
             slim_fabric,
             unpack_fabric,
@@ -561,10 +560,7 @@ class TierEngine:
             )
             lanes_j = jnp.asarray(lanes)
             if cl._donate:
-                with _no_persistent_cache():
-                    st, fb = scatter_donate_jit(
-                        st, fb, lanes_j, st_rows, fb_rows
-                    )
+                st, fb = scatter_donate_jit(st, fb, lanes_j, st_rows, fb_rows)
             else:
                 st, fb = scatter_jit(st, fb, lanes_j, st_rows, fb_rows)
 

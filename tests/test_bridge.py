@@ -79,12 +79,6 @@ def test_leader_host_failure_and_failover():
 def test_bridge_over_wire_codec():
     """Same spanning-group election/commit, but every message crosses the
     bridge as raftpb wire bytes through the C++ codec."""
-    from raft_tpu.runtime.native import native_available
-
-    if not native_available():
-        import pytest
-
-        pytest.skip("native library not buildable")
     bridge, hosts = make_spanning_group()
     bridge.wire = True
     hosts[0].campaign(0)

@@ -4,12 +4,8 @@ rules of raftpb/raft.pb.go) and round-trips."""
 import pytest
 
 from raft_tpu.api.rawnode import Entry, Message, Snapshot
-from raft_tpu.runtime.native import native_available
 from raft_tpu.types import MessageType as MT
 
-pytestmark = pytest.mark.skipif(
-    not native_available(), reason="native library not buildable"
-)
 
 
 def test_msgapp_golden_bytes():

@@ -540,17 +540,10 @@ def _sharded_twin(monkeypatch, diet):
     return sh
 
 
-def test_sharded_digest_identity(monkeypatch):
+def test_sharded_digest_identity(monkeypatch, no_persistent_cache):
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 virtual devices")
-    # the CPU executable serializer aborts on large shard_map programs
-    # (see tests/test_sharded.py); skip persisting them
-    old = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
-    try:
-        off = _sharded_twin(monkeypatch, "0")
-        on = _sharded_twin(monkeypatch, "1")
-        assert is_packed(on.inner.state)
-        assert _digest(on.host_state()) == _digest(off.host_state())
-    finally:
-        jax.config.update("jax_compilation_cache_dir", old)
+    off = _sharded_twin(monkeypatch, "0")
+    on = _sharded_twin(monkeypatch, "1")
+    assert is_packed(on.inner.state)
+    assert _digest(on.host_state()) == _digest(off.host_state())

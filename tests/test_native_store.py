@@ -6,11 +6,31 @@ import random
 import pytest
 
 from raft_tpu.api.rawnode import Entry, EntryStore
-from raft_tpu.runtime.native import make_payload_store, native_available
+from raft_tpu.runtime import native
+from raft_tpu.runtime.native import make_payload_store
 
-pytestmark = pytest.mark.skipif(
-    not native_available(), reason="native lib not buildable"
-)
+
+def test_build_is_stamped_with_the_sources_it_was_built_from():
+    # a library copied in from elsewhere (or built from other sources) is
+    # not trusted: only a matching source stamp skips the rebuild
+    native.build()
+    with open(native._STAMP) as f:
+        assert f.read().strip() == native._sources_digest()
+    mtime = native.os.path.getmtime(native._SO)
+    native.build()
+    assert native.os.path.getmtime(native._SO) == mtime
+    with open(native._STAMP, "w") as f:
+        f.write("stale\n")
+    native.build()
+    with open(native._STAMP) as f:
+        assert f.read().strip() == native._sources_digest()
+
+
+def test_failed_build_raises(monkeypatch):
+    monkeypatch.setattr(native, "_SRCS", native._SRCS)
+    monkeypatch.setattr(native, "_DIR", "/nonexistent-native-dir")
+    with pytest.raises((RuntimeError, OSError)):
+        native.build(force=True)
 
 
 def test_basic_roundtrip():

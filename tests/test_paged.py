@@ -584,24 +584,17 @@ def _sharded_twin(monkeypatch, paged):
     return sh
 
 
-def test_sharded_digest_identity(monkeypatch):
+def test_sharded_digest_identity(monkeypatch, no_persistent_cache):
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 virtual devices")
-    # the CPU executable serializer aborts on large shard_map programs
-    # (see tests/test_sharded.py); skip persisting them
-    old = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
-    try:
-        off = _sharded_twin(monkeypatch, "0")
-        on = _sharded_twin(monkeypatch, "1")
-        assert on.inner.paged is not None
-        assert on.inner._paged_segs == 8
-        # default pool (N*kmax + 8 = 80) divides over the 8 shards and
-        # every page id stays inside its shard's 10-row sub-pool
-        assert int(np.asarray(on.inner.paged.pt).max()) < 80 // 8
-        assert _digest(on.host_state()) == _digest(off.host_state())
-    finally:
-        jax.config.update("jax_compilation_cache_dir", old)
+    off = _sharded_twin(monkeypatch, "0")
+    on = _sharded_twin(monkeypatch, "1")
+    assert on.inner.paged is not None
+    assert on.inner._paged_segs == 8
+    # default pool (N*kmax + 8 = 80) divides over the 8 shards and
+    # every page id stays inside its shard's 10-row sub-pool
+    assert int(np.asarray(on.inner.paged.pt).max()) < 80 // 8
+    assert _digest(on.host_state()) == _digest(off.host_state())
 
 
 # -- in-kernel paging (RAFT_TPU_PAGED_INKERNEL, ISSUE 17) ------------------
@@ -768,7 +761,9 @@ def test_check_pool_segments_rejects_bad_geometry():
         pgmod.check_pool_segments(plan8, 4)
 
 
-def test_sharded_inkernel_xla_digest_identity(monkeypatch):
+def test_sharded_inkernel_xla_digest_identity(
+    monkeypatch, no_persistent_cache
+):
     """Sharded in-kernel XLA twin: paging runs per round inside
     shard_map (segment = shard), digest-identical to the host-boundary
     sharded run."""
@@ -776,25 +771,22 @@ def test_sharded_inkernel_xla_digest_identity(monkeypatch):
 
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 virtual devices")
-    old = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
-    try:
-        off = _sharded_twin(monkeypatch, "0")
-        _set_env(monkeypatch, paged="1", paged_inkernel="1")
-        on = ShardedFusedCluster(n_groups=8, n_voters=3, seed=13,
-                                 shape=_small_shape())
-        on.run(40)
-        on.run(16, auto_propose=True, auto_compact_lag=8)
-        on.check_no_errors()
-        assert on.inner._paged_inkernel
-        assert on.inner._paged_segs == 8  # xla engine: segment = shard
-        assert _digest(on.host_state()) == _digest(off.host_state())
-    finally:
-        jax.config.update("jax_compilation_cache_dir", old)
+    off = _sharded_twin(monkeypatch, "0")
+    _set_env(monkeypatch, paged="1", paged_inkernel="1")
+    on = ShardedFusedCluster(n_groups=8, n_voters=3, seed=13,
+                             shape=_small_shape())
+    on.run(40)
+    on.run(16, auto_propose=True, auto_compact_lag=8)
+    on.check_no_errors()
+    assert on.inner._paged_inkernel
+    assert on.inner._paged_segs == 8  # xla engine: segment = shard
+    assert _digest(on.host_state()) == _digest(off.host_state())
 
 
 @pytest.mark.slow
-def test_sharded_inkernel_pallas_segments_and_digest(monkeypatch):
+def test_sharded_inkernel_pallas_segments_and_digest(
+    monkeypatch, no_persistent_cache
+):
     """Sharded in-kernel pallas: two shards x two tiles per shard ->
     four allocation segments; still digest-identical to the host-boundary
     sharded run. Interpret-mode pallas under shard_map is minutes-slow on
@@ -803,26 +795,21 @@ def test_sharded_inkernel_pallas_segments_and_digest(monkeypatch):
 
     if len(jax.devices()) < 2:
         pytest.skip("needs 2 virtual devices")
-    old = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
-    try:
-        dev = jax.devices()[:2]
-        _set_env(monkeypatch, paged="1")
-        off = ShardedFusedCluster(n_groups=8, n_voters=3, seed=13,
-                                  shape=_small_shape(), devices=dev)
-        off.run(24, auto_propose=True, auto_compact_lag=8)
-        off.check_no_errors()
-        _set_env(monkeypatch, paged="1", paged_inkernel="1")
-        on = ShardedFusedCluster(n_groups=8, n_voters=3, seed=13,
-                                 shape=_small_shape(), devices=dev,
-                                 engine="pallas", tile_lanes=6)
-        on.run(24, auto_propose=True, auto_compact_lag=8)
-        on.check_no_errors()
-        assert on.inner.engine == "pallas"
-        assert on.inner._paged_segs == 2 * (12 // 6)
-        assert _digest(on.host_state()) == _digest(off.host_state())
-    finally:
-        jax.config.update("jax_compilation_cache_dir", old)
+    dev = jax.devices()[:2]
+    _set_env(monkeypatch, paged="1")
+    off = ShardedFusedCluster(n_groups=8, n_voters=3, seed=13,
+                              shape=_small_shape(), devices=dev)
+    off.run(24, auto_propose=True, auto_compact_lag=8)
+    off.check_no_errors()
+    _set_env(monkeypatch, paged="1", paged_inkernel="1")
+    on = ShardedFusedCluster(n_groups=8, n_voters=3, seed=13,
+                             shape=_small_shape(), devices=dev,
+                             engine="pallas", tile_lanes=6)
+    on.run(24, auto_propose=True, auto_compact_lag=8)
+    on.check_no_errors()
+    assert on.inner.engine == "pallas"
+    assert on.inner._paged_segs == 2 * (12 // 6)
+    assert _digest(on.host_state()) == _digest(off.host_state())
 
 
 # -- tier x paged (satellite: eviction must capture the deep paged tail) ---

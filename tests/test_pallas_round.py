@@ -9,12 +9,10 @@ Mosaic on TPU). The acceptance bar from the promotion PR:
    the lane-offset chaos PRNG reconstruction are exact, not approximate).
 2. Tile invariant: tile_lanes % v == 0 and tile_lanes | n, rejected with
    a clear TileError that is never swallowed by the fallback.
-3. Graceful degradation: a lowering failure (forced here via
-   RAFT_TPU_PALLAS_FORCE_FAIL) logs once through the metrics host plane
-   and flips the cluster to the XLA engine with the carry intact.
-4. Donation composes: the donating pallas twin runs under the jax 0.4.37
-   persistent-cache fence (fused._no_persistent_cache), deletes the old
-   carry, and changes no value vs the copying twin.
+3. No silent degradation: a kernel failure raises out of the dispatch
+   and the cluster stays on the pallas engine.
+4. Donation composes: the donating pallas twin deletes the old carry and
+   changes no value vs the copying twin.
 
 Plus the satellites: BlockedFusedCluster ops-cache LRU regression, the
 blocked/sharded engine passthrough, and the tile helper unit coverage.
@@ -31,7 +29,6 @@ import jax
 
 from raft_tpu.chaos.device import probability
 from raft_tpu.config import Shape
-from raft_tpu.metrics.host import ENGINE_EVENTS
 from raft_tpu.ops import fused
 from raft_tpu.ops import pallas_round as plr
 from raft_tpu.ops.fused import FusedCluster
@@ -59,8 +56,8 @@ def _assert_trees_equal(a, b, what):
         assert np.array_equal(np.asarray(x), np.asarray(y)), (what, path)
 
 
-def _fallbacks():
-    return ENGINE_EVENTS.get("engine_pallas_fallback")
+def _boom(*a, **k):
+    raise RuntimeError("forced pallas kernel failure")
 
 
 def _digest(*trees):
@@ -140,15 +137,12 @@ def test_tile_invariants_rejected():
         plr.check_tile(12, 3, 9)
     with pytest.raises(plr.TileError, match=">= 1"):
         plr.check_tile(12, 3, 0)
-    # TileError is a config error: the cluster raises it and does NOT
-    # fall back (the engine stays pallas, nothing is logged)
-    before = _fallbacks()
+    # TileError is a config error: the cluster raises it and stays pallas
     c = FusedCluster(G, V, seed=1, shape=_shape(), engine="pallas",
                      tile_lanes=4)
     with pytest.raises(plr.TileError, match="multiple of v"):
         c.run(1)
     assert c.engine == "pallas"
-    assert _fallbacks() == before
 
 
 def test_autotune_sweep_caches_winner():
@@ -203,47 +197,36 @@ def test_engine_selection(monkeypatch):
         FusedCluster(G, V, seed=1, shape=_shape())
 
 
-# -- 3. forced lowering failure -> fallback --------------------------------
+# -- 3. kernel failure -> raise, never a silent engine switch ----------------
 
 
-def test_forced_lowering_failure_falls_back(monkeypatch):
+def test_kernel_failure_raises(monkeypatch):
     monkeypatch.setenv("RAFT_TPU_METRICS", "0")
     monkeypatch.setenv("RAFT_TPU_CHAOS", "0")
-    ref = FusedCluster(G, V, seed=5, shape=_shape())
-    ref.run(4, auto_propose=True)
-    before = _fallbacks()
-    monkeypatch.setenv("RAFT_TPU_PALLAS_FORCE_FAIL", "1")
     c = FusedCluster(G, V, seed=5, shape=_shape(), engine="pallas",
                      tile_lanes=TILE)
-    c.run(4, auto_propose=True)  # must not raise
-    assert c.engine == "xla"
-    assert _fallbacks() == before + 1
-    _assert_trees_equal(ref.state, c.state, "fallback redrive diverged")
-    # sticky: later runs go straight to XLA, no second fallback record
-    ref.run(4, auto_propose=True)
-    c.run(4, auto_propose=True)
-    assert _fallbacks() == before + 1
-    _assert_trees_equal(ref.state, c.state, "post-fallback run diverged")
+    monkeypatch.setattr(plr, "round_jit_twin", lambda donate: _boom)
+    with pytest.raises(RuntimeError, match="forced pallas kernel failure"):
+        c.run(4, auto_propose=True)
+    assert c.engine == "pallas"
 
 
-# -- 4. donation x pallas under the cache fence ----------------------------
+# -- 4. donation x pallas ---------------------------------------------------
 
 
-def test_donation_composes_with_pallas_under_fence(monkeypatch):
+def test_donation_composes_with_pallas(monkeypatch):
     monkeypatch.setenv("RAFT_TPU_METRICS", "0")
     monkeypatch.setenv("RAFT_TPU_CHAOS", "0")
     monkeypatch.setenv("RAFT_TPU_DONATE", "1")
-    cache_flag = jax.config.jax_enable_compilation_cache
     c = FusedCluster(G, V, seed=9, shape=_shape(), engine="pallas",
                      tile_lanes=TILE)
     assert c._donate
     st0, fab0 = c.state, c.fab
     c.run(4, auto_propose=True)
     assert c.engine == "pallas"  # really dispatched on the kernel path
-    # the donated carry died in place; the fence restored the cache flag
+    # the donated carry died in place
     assert st0.term.is_deleted()
     assert fab0.rep.kind.is_deleted()
-    assert jax.config.jax_enable_compilation_cache == cache_flag
     c.run(4, auto_propose=True)
 
     monkeypatch.setenv("RAFT_TPU_DONATE", "0")
@@ -397,14 +380,11 @@ def test_trace_plane_routes_to_k1(monkeypatch):
 
 
 def test_donation_composes_with_megakernel(monkeypatch):
-    """Donation x cache-fence x K>1: the donating twin under the jax
-    0.4.37 fence deletes the old carry and changes no value. (K=2 keeps
-    the interpreted program small — the fence forces recompiles, so this
-    test pays the megakernel trace cost 4x.)"""
+    """Donation x K>1: the donating twin deletes the old carry and
+    changes no value. (K=2 keeps the interpreted program small.)"""
     monkeypatch.setenv("RAFT_TPU_METRICS", "0")
     monkeypatch.setenv("RAFT_TPU_CHAOS", "0")
     monkeypatch.setenv("RAFT_TPU_DONATE", "1")
-    cache_flag = jax.config.jax_enable_compilation_cache
     c = FusedCluster(G, V, seed=9, shape=_shape(), engine="pallas",
                      tile_lanes=TILE, rounds_per_call=2)
     assert c._donate
@@ -412,7 +392,6 @@ def test_donation_composes_with_megakernel(monkeypatch):
     c.run(5, auto_propose=True)  # 2 full K=2 calls + a 1-round tail
     assert c.engine == "pallas"
     assert st0.term.is_deleted()
-    assert jax.config.jax_enable_compilation_cache == cache_flag
     c.run(5, auto_propose=True)
 
     monkeypatch.setenv("RAFT_TPU_DONATE", "0")
@@ -570,26 +549,20 @@ def test_sharded_straddle_vs_pallas(monkeypatch):
     with pytest.raises(ValueError, match="straddle"):
         ShardedFusedCluster(G, V, seed=1, shape=_shape(), engine="pallas",
                             straddle=True, devices=dev)
-    # env-selected pallas degrades to XLA with one host-plane record
-    before = _fallbacks()
+    # so is an env-selected pallas engine: no quiet switch to XLA
     monkeypatch.setenv("RAFT_TPU_ENGINE", "pallas")
-    s = ShardedFusedCluster(G, V, seed=1, shape=_shape(), straddle=True,
+    with pytest.raises(ValueError, match="straddle"):
+        ShardedFusedCluster(G, V, seed=1, shape=_shape(), straddle=True,
                             devices=dev)
-    assert s.inner.engine == "xla"
-    assert _fallbacks() == before + 1
 
 
-def test_sharded_forced_failure_falls_back(monkeypatch):
+def test_sharded_kernel_failure_raises(monkeypatch):
     monkeypatch.setenv("RAFT_TPU_METRICS", "0")
     monkeypatch.setenv("RAFT_TPU_CHAOS", "0")
     dev = jax.devices()[:2]
-    ref = ShardedFusedCluster(G, V, seed=5, shape=_shape(), devices=dev)
-    ref.run(4, auto_propose=True)
-    before = _fallbacks()
-    monkeypatch.setenv("RAFT_TPU_PALLAS_FORCE_FAIL", "1")
     s = ShardedFusedCluster(G, V, seed=5, shape=_shape(), engine="pallas",
                             tile_lanes=V, devices=dev)
-    s.run(4, auto_propose=True)
-    assert s.inner.engine == "xla"
-    assert _fallbacks() == before + 1
-    _assert_trees_equal(ref.inner.state, s.inner.state, "sharded fallback")
+    monkeypatch.setattr(plr, "pallas_rounds", _boom)
+    with pytest.raises(RuntimeError, match="forced pallas kernel failure"):
+        s.run(4, auto_propose=True)
+    assert s.inner.engine == "pallas"

@@ -6,6 +6,7 @@ import pytest
 
 import jax.numpy as jnp
 
+from raft_tpu.ops import pallas_round as plr
 from raft_tpu.ops import quorum as qr
 from raft_tpu.ops import quorum_pallas as qp
 from raft_tpu.ops.quorum_pallas import (
@@ -86,23 +87,28 @@ def test_joint_dispatch_defaults_to_pallas(monkeypatch):
         joint_committed_dispatch(match, m_in, m_out, engine="bogus")
 
 
-def test_joint_dispatch_falls_back_on_kernel_failure(monkeypatch):
-    """A pallas lowering failure degrades to XLA with a once-logged engine
-    event (metrics/host.py record_engine_fallback) instead of erroring."""
-    from raft_tpu.metrics import host as mhost
-
+def test_joint_dispatch_kernel_failure_raises(monkeypatch):
+    """A pallas kernel failure propagates: no silent switch to XLA."""
     match, m_in, m_out = _joint_case(seed=7)
-    want = qr.joint_committed(match, m_in, m_out)
 
     def boom(*a, **k):
         raise RuntimeError("forced quorum kernel failure")
 
     monkeypatch.setattr(qp, "joint_committed_pallas", boom)
-    before = mhost.ENGINE_EVENTS.get("engine_pallas_fallback")
-    got = joint_committed_dispatch(match, m_in, m_out, engine="pallas")
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    after = mhost.ENGINE_EVENTS.get("engine_pallas_fallback")
-    assert after == before + 1
+    with pytest.raises(RuntimeError, match="forced quorum kernel failure"):
+        joint_committed_dispatch(match, m_in, m_out, engine="pallas")
+
+
+def test_interpret_is_never_inferred_from_the_backend(monkeypatch):
+    """Off a TPU, a kernel not explicitly interpreted fails instead of
+    quietly running in the interpreter."""
+    match, m_in, m_out = _joint_case(seed=11)
+    monkeypatch.setenv("RAFT_TPU_PALLAS_INTERPRET", "0")
+    assert not plr.default_interpret()
+    with pytest.raises(Exception):
+        joint_committed_dispatch(match, m_in, m_out, engine="pallas")
+    monkeypatch.setenv("RAFT_TPU_PALLAS_INTERPRET", "1")
+    assert plr.default_interpret()
 
 
 def test_joint_dispatch_delegation_via_quorum():

@@ -9,16 +9,8 @@ from raft_tpu.cluster import Cluster
 from raft_tpu.parallel.sharded import ShardedCluster
 
 
-@pytest.fixture(scope="module", autouse=True)
-def _no_persistent_cache():
-    """XLA's CPU executable serializer aborts the process on this module's
-    largest shard_map programs (fatal abort inside
-    compilation_cache.put_executable_and_time); skip persisting them — the
-    correctness runs don't need cross-run caching."""
-    old = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
-    yield
-    jax.config.update("jax_compilation_cache_dir", old)
+# the largest shard_map programs abort XLA's CPU executable serializer
+pytestmark = pytest.mark.usefixtures("no_persistent_cache")
 
 
 @pytest.fixture(scope="module")
@@ -174,6 +166,7 @@ def test_fused_straddling_groups_elect_and_commit(devices):
     assert (com1 - com0 >= 10).all()
 
 
+@pytest.mark.slow  # ~90 s; straddle election/commit stays in tier 1
 def test_fused_straddle_matches_unsharded_bitwise(devices):
     """The halo router computes the same global delivery as the
     single-device fabric routing, so a straddling sharded run must land in
