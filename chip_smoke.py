@@ -42,29 +42,26 @@ import json
 import os
 import sys
 import time
-from dataclasses import dataclass
 
 
-@dataclass(frozen=True)
-class Sizes:
-    groups: int = 1_048_576
-    block_groups: int = 65_536
-    voters: int = 3
-    window: int = 16
-    entries: int = 2
-    round_chunk: int = 8
-    elect_bound: int = 640
-    steady_rounds: int = 64
-    sessions: int = 1024
-    serve_bound: int = 256
-    ref_groups: int = 4096
-    ref_rounds: int = 64
-    quorum_lanes: int = 196_608
-    mesh_rounds: int = 64
-    seed: int = 42
-
-
-FULL = Sizes()
+# the deployment: 1,048,576 RF-3 groups as 16 resident blocks of 65,536
+GROUPS = 1_048_576
+BLOCK_GROUPS = 65_536
+VOTERS = 3
+WINDOW = 16
+ENTRIES = 2
+ROUND_CHUNK = 8
+ELECT_BOUND = 640
+STEADY_ROUNDS = 64
+SESSIONS = 1024
+SERVE_BOUND = 256
+REF_GROUPS = 4096
+REF_ROUNDS = 64
+QUORUM_LANES = 196_608
+MESH_ROUNDS = 64
+SEED = 42
+# compaction trails the commit by half the window (bench.py's choice)
+LAG = min(8, WINDOW // 2)
 
 
 class CompileClock:
@@ -110,23 +107,17 @@ def peak_bytes(dev, key: str = "peak_bytes_in_use") -> int | None:
     return None if stats is None else stats.get(key)
 
 
-def block_shape(sz: Sizes, block_groups: int):
+def block_shape(block_groups: int):
     from raft_tpu.config import Shape
 
-    v = sz.voters
     return Shape(
-        n_lanes=block_groups * v,
-        max_peers=v,
-        log_window=sz.window,
-        max_msg_entries=sz.entries,
-        max_inflight=sz.entries,
+        n_lanes=block_groups * VOTERS,
+        max_peers=VOTERS,
+        log_window=WINDOW,
+        max_msg_entries=ENTRIES,
+        max_inflight=ENTRIES,
         max_read_index=2,
     )
-
-
-def lag(sz: Sizes) -> int:
-    # compaction trails the commit by half the window (bench.py's choice)
-    return min(8, sz.window // 2)
 
 
 def trajectory_digest(cluster) -> str:
@@ -140,16 +131,16 @@ def trajectory_digest(cluster) -> str:
     return h.hexdigest()
 
 
-def groups_with_leader(cluster, sz: Sizes) -> int:
+def groups_with_leader(cluster) -> int:
     from raft_tpu.types import StateType
 
-    st = cluster.state_columns("state")["state"].reshape(-1, sz.voters)
+    st = cluster.state_columns("state")["state"].reshape(-1, VOTERS)
     return int((st == int(StateType.LEADER)).any(axis=1).sum())
 
 
-def group_committed(cluster, sz: Sizes):
+def group_committed(cluster):
     return cluster.state_columns("committed")["committed"].reshape(
-        -1, sz.voters
+        -1, VOTERS
     ).max(axis=1)
 
 
@@ -166,7 +157,7 @@ def native_phase() -> dict:
     }
 
 
-def fleet_phase(sz: Sizes, clock: CompileClock, dev):
+def fleet_phase(clock: CompileClock, dev):
     from raft_tpu.ops.fused import donation_enabled
     from raft_tpu.scheduler import BlockedFusedCluster
 
@@ -174,42 +165,42 @@ def fleet_phase(sz: Sizes, clock: CompileClock, dev):
         raise RuntimeError("carry donation is off; the smoke runs its default")
     mark, t0 = clock.mark(), time.perf_counter()
     c = BlockedFusedCluster(
-        sz.groups, sz.voters, block_groups=sz.block_groups, seed=sz.seed,
-        shape=block_shape(sz, sz.block_groups), round_chunk=sz.round_chunk,
+        GROUPS, VOTERS, block_groups=BLOCK_GROUPS, seed=SEED,
+        shape=block_shape(BLOCK_GROUPS), round_chunk=ROUND_CHUNK,
     )
-    step = sz.round_chunk * 4
+    step = ROUND_CHUNK * 4
     rounds = 0
     while True:
-        c.run(step, auto_propose=True, auto_compact_lag=lag(sz))
+        c.run(step, auto_propose=True, auto_compact_lag=LAG)
         rounds += step
-        elected = groups_with_leader(c, sz)
-        if elected == sz.groups:
+        elected = groups_with_leader(c)
+        if elected == GROUPS:
             break
-        if rounds >= sz.elect_bound:
+        if rounds >= ELECT_BOUND:
             raise RuntimeError(
-                f"election stalled: {elected}/{sz.groups} groups have a "
+                f"election stalled: {elected}/{GROUPS} groups have a "
                 f"leader after {rounds} rounds"
             )
-    before = group_committed(c, sz)
-    c.run(sz.steady_rounds, auto_propose=True, auto_compact_lag=lag(sz))
-    after = group_committed(c, sz)
+    before = group_committed(c)
+    c.run(STEADY_ROUNDS, auto_propose=True, auto_compact_lag=LAG)
+    after = group_committed(c)
     c.check_no_errors()
     advanced = int((after > before).sum())
-    if advanced != sz.groups:
+    if advanced != GROUPS:
         raise RuntimeError(
-            f"committed advanced in {advanced}/{sz.groups} groups over "
-            f"{sz.steady_rounds} rounds"
+            f"committed advanced in {advanced}/{GROUPS} groups over "
+            f"{STEADY_ROUNDS} rounds"
         )
     emit({
         "phase": "fleet",
-        "groups": sz.groups,
-        "voters": sz.voters,
+        "groups": GROUPS,
+        "voters": VOTERS,
         "blocks": c.k,
-        "block_groups": sz.block_groups,
+        "block_groups": BLOCK_GROUPS,
         "donate": True,
         "rounds_to_elect": rounds,
         "groups_elected": elected,
-        "steady_rounds": sz.steady_rounds,
+        "steady_rounds": STEADY_ROUNDS,
         "groups_committing": advanced,
         "min_commit_advance": int((after - before).min()),
         "total_committed": c.total_committed(),
@@ -220,19 +211,19 @@ def fleet_phase(sz: Sizes, clock: CompileClock, dev):
     return c
 
 
-def served_phase(sz: Sizes, clock: CompileClock, dev, cluster) -> None:
+def served_phase(clock: CompileClock, dev, cluster) -> None:
     from raft_tpu.serve import Rejected, ServeLoop
 
     mark, t0 = clock.mark(), time.perf_counter()
-    loop = ServeLoop(cluster, auto_compact_lag=lag(sz))
-    loop.bootstrap(max_rounds=sz.serve_bound)
-    sessions = [loop.open_session(f"tenant-{i}") for i in range(sz.sessions)]
+    loop = ServeLoop(cluster, auto_compact_lag=LAG)
+    loop.bootstrap(max_rounds=SERVE_BOUND)
+    sessions = [loop.open_session(f"tenant-{i}") for i in range(SESSIONS)]
     value = {s.id: f"value-{s.tenant}" for s in sessions}
     puts = [loop.put(s, f"key/{s.tenant}", value[s.id]) for s in sessions]
     rejected = [p for p in puts if isinstance(p, Rejected)]
     if rejected:
         raise RuntimeError(f"{len(rejected)} puts rejected: {rejected[:3]}")
-    if not loop.drain(sz.serve_bound):
+    if not loop.drain(SERVE_BOUND):
         raise RuntimeError(f"{loop.outstanding} puts unacknowledged")
     if not all(t.done and t.applied for t in puts):
         raise RuntimeError("a put was notified without being applied")
@@ -240,7 +231,7 @@ def served_phase(sz: Sizes, clock: CompileClock, dev, cluster) -> None:
     rejected = [g for g in gets if isinstance(g, Rejected)]
     if rejected:
         raise RuntimeError(f"{len(rejected)} gets rejected: {rejected[:3]}")
-    if not loop.drain(sz.serve_bound):
+    if not loop.drain(SERVE_BOUND):
         raise RuntimeError(f"{loop.outstanding} gets unanswered")
     wrong = [
         g for s, g in zip(sessions, gets)
@@ -258,7 +249,7 @@ def served_phase(sz: Sizes, clock: CompileClock, dev, cluster) -> None:
     cluster.check_no_errors()
     emit({
         "phase": "served",
-        "groups": sz.groups,
+        "groups": GROUPS,
         "sessions": len(sessions),
         "groups_touched": len({s.group for s in sessions}),
         "puts_acked": len(puts),
@@ -273,7 +264,7 @@ def served_phase(sz: Sizes, clock: CompileClock, dev, cluster) -> None:
     })
 
 
-def reference_phase(sz: Sizes, clock: CompileClock, dev) -> None:
+def reference_phase(clock: CompileClock, dev) -> None:
     import jax
 
     from raft_tpu.ops.fused import FusedCluster
@@ -281,12 +272,12 @@ def reference_phase(sz: Sizes, clock: CompileClock, dev) -> None:
     def run_on(d):
         with jax.default_device(d):
             c = FusedCluster(
-                sz.ref_groups, sz.voters, seed=sz.seed,
-                shape=block_shape(sz, sz.ref_groups),
+                REF_GROUPS, VOTERS, seed=SEED,
+                shape=block_shape(REF_GROUPS),
             )
-            for _ in range(sz.ref_rounds // sz.round_chunk):
-                c.run(sz.round_chunk, auto_propose=True,
-                      auto_compact_lag=lag(sz))
+            for _ in range(REF_ROUNDS // ROUND_CHUNK):
+                c.run(ROUND_CHUNK, auto_propose=True,
+                      auto_compact_lag=LAG)
         if c.state.term.devices() != {d}:
             raise RuntimeError(f"reference fleet left {d}")
         c.check_no_errors()
@@ -299,8 +290,8 @@ def reference_phase(sz: Sizes, clock: CompileClock, dev) -> None:
         raise RuntimeError(f"chip digest {chip} != cpu digest {cpu}")
     emit({
         "phase": "reference",
-        "groups": sz.ref_groups,
-        "rounds": sz.ref_rounds,
+        "groups": REF_GROUPS,
+        "rounds": REF_ROUNDS,
         "leaders": chip_leaders,
         "chip_digest": chip,
         "cpu_digest": cpu,
@@ -309,7 +300,7 @@ def reference_phase(sz: Sizes, clock: CompileClock, dev) -> None:
     })
 
 
-def pallas_phase(sz: Sizes, clock: CompileClock, dev) -> None:
+def pallas_phase(clock: CompileClock, dev) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -317,8 +308,8 @@ def pallas_phase(sz: Sizes, clock: CompileClock, dev) -> None:
     from raft_tpu.ops import quorum, quorum_pallas
 
     mark, t0 = clock.mark(), time.perf_counter()
-    rng = np.random.default_rng(sz.seed)
-    n, v = sz.quorum_lanes, sz.voters
+    rng = np.random.default_rng(SEED)
+    n, v = QUORUM_LANES, VOTERS
     match = jnp.asarray(rng.integers(0, 1 << 20, (n, v), dtype=np.int32))
     m_in = jnp.asarray(rng.random((n, v)) < 0.8)
     m_out = jnp.asarray(rng.random((n, v)) < 0.5)
@@ -337,7 +328,7 @@ def pallas_phase(sz: Sizes, clock: CompileClock, dev) -> None:
             raise RuntimeError(f"{name} differs from ops/quorum.py")
     if joint.devices() != {dev}:
         raise RuntimeError("the quorum kernel ran off the chip")
-    refusal = round_engine_refusal(sz)
+    refusal = round_engine_refusal()
     emit({
         "phase": "pallas",
         "quorum_lanes": n,
@@ -349,7 +340,7 @@ def pallas_phase(sz: Sizes, clock: CompileClock, dev) -> None:
     })
 
 
-def round_engine_refusal(sz: Sizes) -> str:
+def round_engine_refusal() -> str:
     """engine="pallas" must raise on the chip with Mosaic's message (its
     round body does not lower, ROADMAP C3) — never fall back to XLA."""
     from raft_tpu.ops import pallas_round as plr
@@ -357,8 +348,8 @@ def round_engine_refusal(sz: Sizes) -> str:
 
     g = 1024
     c = FusedCluster(
-        g, sz.voters, seed=sz.seed, shape=block_shape(sz, g),
-        engine="pallas", tile_lanes=plr.default_tile(g * sz.voters, sz.voters),
+        g, VOTERS, seed=SEED, shape=block_shape(g),
+        engine="pallas", tile_lanes=plr.default_tile(g * VOTERS, VOTERS),
         rounds_per_call=1,
     )
     try:
@@ -370,32 +361,32 @@ def round_engine_refusal(sz: Sizes) -> str:
     raise RuntimeError("the pallas round engine ran: time it (ROADMAP A1)")
 
 
-def mesh_phase(sz: Sizes, clock: CompileClock, devices) -> None:
+def mesh_phase(clock: CompileClock, devices) -> None:
     import jax
 
     from raft_tpu.parallel.mesh import MeshBlockedCluster
     from raft_tpu.scheduler import BlockedFusedCluster
 
     def drive(c):
-        for _ in range(sz.mesh_rounds // sz.round_chunk):
-            c.run(sz.round_chunk, auto_propose=True, auto_compact_lag=lag(sz))
+        for _ in range(MESH_ROUNDS // ROUND_CHUNK):
+            c.run(ROUND_CHUNK, auto_propose=True, auto_compact_lag=LAG)
         c.check_no_errors()
-        return trajectory_digest(c), groups_with_leader(c, sz)
+        return trajectory_digest(c), groups_with_leader(c)
 
-    shape = block_shape(sz, sz.block_groups)
+    shape = block_shape(BLOCK_GROUPS)
     mark, t0 = clock.mark(), time.perf_counter()
     mesh = MeshBlockedCluster(
-        sz.groups, sz.voters, block_groups=sz.block_groups, devices=devices,
-        seed=sz.seed, shape=shape, round_chunk=sz.round_chunk,
+        GROUPS, VOTERS, block_groups=BLOCK_GROUPS, devices=devices,
+        seed=SEED, shape=shape, round_chunk=ROUND_CHUNK,
     )
     built = [peak_bytes(d, "bytes_in_use") for d in devices]
     mesh_digest, mesh_elected = drive(mesh)
     emit({
         "phase": "mesh",
-        "groups": sz.groups,
+        "groups": GROUPS,
         "chips": len(devices),
         "blocks": mesh.k,
-        "rounds": sz.mesh_rounds,
+        "rounds": MESH_ROUNDS,
         "groups_elected": mesh_elected,
         "bytes_in_use_after_build": built,
         "peak_bytes_in_use": [peak_bytes(d) for d in devices],
@@ -407,15 +398,15 @@ def mesh_phase(sz: Sizes, clock: CompileClock, devices) -> None:
     mark, t0 = clock.mark(), time.perf_counter()
     with jax.default_device(devices[0]):
         mono = BlockedFusedCluster(
-            sz.groups, sz.voters, block_groups=sz.block_groups,
-            seed=sz.seed, shape=shape, round_chunk=sz.round_chunk,
+            GROUPS, VOTERS, block_groups=BLOCK_GROUPS,
+            seed=SEED, shape=shape, round_chunk=ROUND_CHUNK,
         )
         mono_digest, mono_elected = drive(mono)
     if mono.blocks[0].state.term.devices() != {devices[0]}:
         raise RuntimeError("the one-chip fleet left device 0")
     emit({
         "phase": "one_chip",
-        "groups": sz.groups,
+        "groups": GROUPS,
         "groups_elected": mono_elected,
         "digest": mono_digest,
         **clock.since(mark),
@@ -427,7 +418,7 @@ def mesh_phase(sz: Sizes, clock: CompileClock, devices) -> None:
         )
 
 
-def run(sz: Sizes, chips: int = 1) -> dict:
+def run(chips: int = 1) -> dict:
     """Run the smoke's phases on jax.devices()[:chips]; raises on any
     failure. Returns the device record of the last line."""
     import jax
@@ -444,21 +435,22 @@ def run(sz: Sizes, chips: int = 1) -> dict:
         "phase": "device",
         "platform": dev.platform,
         "kind": dev.device_kind,
-        "count": len(jax.devices()),
+        "count": len(devices),
+        "visible": len(jax.devices()),
         "jax": jax.__version__,
         "compile_cache": cache,
     })
     if chips == 1:
         emit(native_phase())
-        fleet = fleet_phase(sz, clock, dev)
-        served_phase(sz, clock, dev, fleet)
+        fleet = fleet_phase(clock, dev)
+        served_phase(clock, dev, fleet)
         del fleet
-        reference_phase(sz, clock, dev)
-        pallas_phase(sz, clock, dev)
+        reference_phase(clock, dev)
+        pallas_phase(clock, dev)
     else:
-        mesh_phase(sz, clock, devices)
+        mesh_phase(clock, devices)
     return {"platform": dev.platform, "kind": dev.device_kind,
-            "count": len(jax.devices())}
+            "count": len(devices)}
 
 
 def main(argv=None) -> int:
@@ -476,7 +468,7 @@ def main(argv=None) -> int:
         print(f"chip_smoke: no TPU (jax found {dev.platform})", file=sys.stderr)
         return 2
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    device = run(FULL, chips=args.chips)
+    device = run(chips=args.chips)
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 

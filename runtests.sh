@@ -101,7 +101,7 @@ if [ $# -eq 0 ] || [ "$*" = "tests/" ]; then
     # crash threshold and the wall time drops ~4x.
     run -n 6 --dist loadfile --max-worker-restart 0 \
       $(ls tests/test_*.py | grep -v -e test_sharded -e test_mesh) \
-      && run tests/test_mesh.py \
+      && run tests/test_mesh.py tests/test_mesh_twins.py \
       && run tests/test_sharded.py \
       && smokes
   else
@@ -184,10 +184,12 @@ if [ $# -eq 0 ] || [ "$*" = "tests/" ]; then
     # in-process lockstep twins and the wire-chaos probes
     run_chunk tests/test_fabric.py
     # the mesh-blocked driver gets its own process before test_sharded:
-    # its sharded x blocked twins are all 8-device shard_map programs
-    # (plus one subprocess A/B child trio), same crash profile as
-    # test_sharded, same autouse no-persistent-cache fixture
+    # its sharded x blocked twins are all 8-device shard_map programs,
+    # same crash profile as test_sharded, same no-persistent-cache
+    # fixture; the long twins (donation, metrics + chaos, the subprocess
+    # A/B child trio) are test_mesh_twins.py
     run_chunk tests/test_mesh.py
+    run_chunk tests/test_mesh_twins.py
     run_chunk tests/test_sharded.py
     smokes
   fi

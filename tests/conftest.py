@@ -93,11 +93,25 @@ def pytest_addoption(parser, pluginmanager):
     )
 
 
+# The longest files, each 5-8 minutes on one xdist worker, in that order.
+# Alphabetical order would start them last and leave them to run alone at
+# the tail of the sweep.
+_LONG_FILES = (
+    "test_sharded.py", "test_lockstep_more.py", "test_mesh_twins.py",
+    "test_mesh.py",
+)
+
+
 def pytest_collection_modifyitems(config, items):
-    """Warn when the FULL suite is collected into one process: XLA:CPU
+    """Run the longest files first (loadfile hands files out in collection
+    order; the sort is stable, so every file keeps its own test order).
+
+    Warn when the FULL suite is collected into one process: XLA:CPU
     reproducibly aborts once a few hundred distinct programs have been
     compiled in a single process (see runtests.sh), so the suite must be
     spread over pytest-xdist workers. `./runtests.sh` does this correctly."""
+    rank = {name: i for i, name in enumerate(_LONG_FILES)}
+    items.sort(key=lambda item: rank.get(item.path.name, len(rank)))
     # The xdist controller never collects items, so this hook only runs in
     # workers (PYTEST_XDIST_WORKER/_COUNT set) or in a plain in-process run.
     # Require enough workers that no single process crosses the

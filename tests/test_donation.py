@@ -18,6 +18,10 @@ ops pre-slicing, round_chunk dispatch equivalence, pipeline_depth.
 
 from __future__ import annotations
 
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -153,6 +157,81 @@ def test_lowering_carries_donation_annotation():
     ).as_text()
     assert _has_donation_annotation(donating)
     assert not _has_donation_annotation(copying)
+
+
+# -- 4. donating programs read back from the persistent compile cache ----
+
+# One fresh process: drive a donating (or copying) FusedCluster, then print
+# the donation flag, its persistent-cache hits and misses, and a digest of
+# every state + fabric leaf.
+_CACHE_CHILD = r"""
+import collections
+import hashlib
+import jax
+import numpy as np
+seen = collections.Counter()
+jax.monitoring.register_event_listener(lambda name, **kw: seen.update([name]))
+from raft_tpu.ops.fused import FusedCluster
+c = FusedCluster(8, 3, seed=11)
+for _ in range(3):
+    c.run(8)
+for _ in range(2):
+    c.run(8, auto_propose=True, auto_compact_lag=4)
+c.check_no_errors()
+h = hashlib.sha256()
+for x in jax.tree_util.tree_leaves((c.state, c.fab)):
+    h.update(np.asarray(x).tobytes())
+hits = seen["/jax/compilation_cache/cache_hits"]
+asked = seen["/jax/compilation_cache/compile_requests_use_cache"]
+print(int(c._donate), hits, asked - hits, h.hexdigest())
+"""
+
+
+def cache_children(*runs):
+    """Run one _CACHE_CHILD process per (cache_dir, donate) pair, all at
+    once, and return their (donate, hits, misses, digest) lines."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    procs = [
+        subprocess.Popen(
+            [sys.executable, "-c", _CACHE_CHILD], cwd=repo,
+            env=dict(
+                os.environ,
+                JAX_PLATFORMS="cpu",
+                JAX_COMPILATION_CACHE_DIR=str(cache_dir),
+                JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+                JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0",
+                RAFT_TPU_DONATE=donate,
+                XLA_FLAGS="",
+            ),
+            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+        )
+        for cache_dir, donate in runs
+    ]
+    out = []
+    for p in procs:
+        so, se = p.communicate(timeout=600)
+        assert p.returncode == 0, se[-4000:]
+        d, hits, misses, digest = so.split()[-4:]
+        out.append((int(d), int(hits), int(misses), digest))
+    return out
+
+
+def test_donating_program_from_warm_cache_matches_copying_twin(tmp_path):
+    """On jax 0.4.37 a donating executable read back from the persistent
+    cache mis-executed in about a third of warm processes (donated-adjacent
+    inputs read as zeros), so donating programs once compiled behind a
+    cache fence. That fence is gone: fresh processes that load the donating
+    round from a warm cache must land on the copying twin's digest."""
+    warm = tmp_path / "donate"
+    (d0, _, _, copying), (d1, cold_hits, _, cold) = cache_children(
+        (tmp_path / "copy", "0"), (warm, "1")
+    )
+    assert (d0, d1, cold_hits) == (0, 1, 0)
+    assert cold == copying
+    for d, hits, misses, digest in cache_children(*[(warm, "1")] * 4):
+        # every program, the donating round included, came from the cache
+        assert (d, misses) == (1, 0) and hits > 0
+        assert digest == copying
 
 
 # -- scheduler: wal validation, ops binding, dispatch equivalence ---------

@@ -166,7 +166,6 @@ def test_fused_straddling_groups_elect_and_commit(devices):
     assert (com1 - com0 >= 10).all()
 
 
-@pytest.mark.slow  # ~90 s; straddle election/commit stays in tier 1
 def test_fused_straddle_matches_unsharded_bitwise(devices):
     """The halo router computes the same global delivery as the
     single-device fabric routing, so a straddling sharded run must land in
