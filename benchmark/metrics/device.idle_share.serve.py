@@ -1,0 +1,7 @@
+"""device.idle_share.serve: percent of the traced window in which no
+operation ran on the device (1 - union of op intervals / window), in the
+served cells."""
+
+
+def reduce(rec, trace):
+    return None if trace is None else 100.0 * trace.idle_share
