@@ -170,17 +170,14 @@ def main():
     block = int(os.environ.get("BENCH_BLOCK", 32))
     n_voters = int(os.environ.get("BENCH_VOTERS", 3))
 
-    from raft_tpu.utils.profiling import env_trace_dir, trace
-
-    with trace(env_trace_dir()):
-        if engine == "fused":
-            dt, compile_s, n_leaders, commits, met, probe = run_fused(
-                n_groups, n_voters, n_iters, block, block_groups
-            )
-        else:
-            dt, compile_s, n_leaders, commits, met, probe = run_serial(
-                n_groups, n_voters, n_iters, block
-            )
+    if engine == "fused":
+        dt, compile_s, n_leaders, commits, met, probe = run_fused(
+            n_groups, n_voters, n_iters, block, block_groups
+        )
+    else:
+        dt, compile_s, n_leaders, commits, met, probe = run_serial(
+            n_groups, n_voters, n_iters, block
+        )
 
     groups_ticks_per_sec = n_groups * n_iters * block / dt
     target = 1_000_000.0

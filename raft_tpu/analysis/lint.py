@@ -98,8 +98,6 @@ BENCH_ALLOW = {
     "benches/paged_ab.py": {"child"},
     "benches/pallas_ab.py": {"child"},
     "benches/pallas_probe.py": {"main"},
-    "benches/profile_analyze.py": set(),
-    "benches/profile_capture.py": {"main"},
     "benches/roundtime.py": {"main"},
     "benches/scaling_probe.py": {"measure"},
     "benches/serve_bench.py": {"pct"},

@@ -88,9 +88,10 @@ class NodeHost:
     def __init__(self, batch: RawNodeBatch):
         self.batch = batch
         # per-op-kind wall timings on the loop thread (the reference's
-        # callStats analog): step_<kind>_count / step_<kind>_micros via
-        # stats.snapshot(), registerable with metrics.host.MetricsRegistry
-        self.stats = StepStats()
+        # callStats analog): node.<kind> spans, exported as
+        # step_<kind>_count / step_<kind>_micros via stats.snapshot(),
+        # registerable with metrics.host.MetricsRegistry
+        self.stats = StepStats("node")
         self._ops: queue.Queue[_Op] = queue.Queue()
         self._ready_q: list[queue.Queue[Ready]] = [
             queue.Queue(maxsize=1) for _ in range(batch.shape.n)
@@ -121,14 +122,14 @@ class NodeHost:
             # surface Readys for lanes that want them (readyc select arm);
             # ready_lanes is the batched egress mask — ONE device dispatch
             # for all lanes instead of a scalar has_ready poll per lane
-            with self.stats.timed("ready_poll"):
+            with self.stats.span("node.ready_poll"):
                 lanes = self.batch.ready_lanes()
             for lane in lanes:
                 if self._advance_pending[lane]:
                     continue
                 if not self._ready_q[lane].empty():
                     continue
-                with self.stats.timed("ready_build"):
+                with self.stats.span("node.ready_build"):
                     rd = self.batch.ready(lane)
                 self._advance_pending[lane] = True
                 self._ready_q[lane].put(rd)
@@ -150,7 +151,7 @@ class NodeHost:
                 op.done.set()
             return
         try:
-            with self.stats.timed(op.kind):
+            with self.stats.span(f"node.{op.kind}"):
                 self._execute(op, b)
         except Exception as e:  # surface to caller when waiting
             op.error = e

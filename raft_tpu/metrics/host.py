@@ -34,6 +34,7 @@ shapes Grafana/offline analysis ingest without an adapter.
 
 from __future__ import annotations
 
+import bisect
 import json
 import time
 
@@ -363,14 +364,12 @@ class HostHistogram:
         self.buckets = [0] * (len(self.edges) + 1)
         self.sum = 0
 
-    def observe(self, value: int, n: int = 1):
-        b = len(self.edges)
-        for i, e in enumerate(self.edges):
-            if value <= e:
-                b = i
-                break
-        self.buckets[b] += n
-        self.sum += int(value) * n
+    def observe(self, value, n: int = 1):
+        """File `value` (rounds, or wall ms for the serving plane's stage
+        histograms) n times; the exported sum is truncated to an int."""
+        # the first bucket whose le edge holds the value (+Inf past the last)
+        self.buckets[bisect.bisect_left(self.edges, value)] += n
+        self.sum += value * n
 
     def snapshot(self) -> dict:
         return {

@@ -81,6 +81,7 @@ from raft_tpu.types import (
     VoteResult,
     VoteState,
 )
+from raft_tpu.utils.profiling import StepStats
 
 I32 = jnp.int32
 BOOL = jnp.bool_
@@ -1955,6 +1956,10 @@ class FusedCluster:
         self._wal_pending = None
         self._egress_pending = None
         self._trace_pending = None
+        # host spans of this cluster's dispatch and read drain (sched.enqueue,
+        # serve.drain_reads.*); a ServeLoop or blocked driver shares its own
+        # bag through use_stats
+        self.stats = StepStats()
         # metrics plane (raft_tpu/metrics/): RAFT_TPU_METRICS is read at
         # construction; metrics=None keeps every metrics op out of the jaxpr
         self.metrics = metmod.init_metrics(n) if metmod.metrics_enabled() else None
@@ -2045,6 +2050,11 @@ class FusedCluster:
 
     # -- driving ----------------------------------------------------------
 
+    def use_stats(self, stats: StepStats) -> None:
+        """Record this cluster's host spans into `stats` (the ServeLoop's
+        or the blocked driver's bag)."""
+        self.stats = stats
+
     def run(
         self,
         rounds: int = 1,
@@ -2075,34 +2085,38 @@ class FusedCluster:
         self._flush_stream_fences()
         if self._diet:
             self._diet_headroom(rounds)
-        if self.engine == "pallas":
-            res = self._run_pallas(
-                rounds,
-                ops,
-                do_tick,
-                auto_propose,
-                auto_compact_lag,
-                ops_first_round_only,
-            )
-        else:
-            jit = _fused_rounds_jit if self._donate else _fused_rounds_nodonate_jit
-            res = jit(
-                self.state,
-                self.fab,
-                ops,
-                self.mute,
-                v=self.v,
-                n_rounds=rounds,
-                do_tick=do_tick,
-                auto_propose=auto_propose,
-                auto_compact_lag=auto_compact_lag,
-                ops_first_round_only=ops_first_round_only,
-                paged_inkernel=self._paged_inkernel,
-                metrics=self.metrics,
-                chaos=self.chaos,
-                trace=self.trace,
-                paged=self.paged,
-            )
+        with self.stats.span("sched.enqueue"):
+            if self.engine == "pallas":
+                res = self._run_pallas(
+                    rounds,
+                    ops,
+                    do_tick,
+                    auto_propose,
+                    auto_compact_lag,
+                    ops_first_round_only,
+                )
+            else:
+                jit = (
+                    _fused_rounds_jit if self._donate
+                    else _fused_rounds_nodonate_jit
+                )
+                res = jit(
+                    self.state,
+                    self.fab,
+                    ops,
+                    self.mute,
+                    v=self.v,
+                    n_rounds=rounds,
+                    do_tick=do_tick,
+                    auto_propose=auto_propose,
+                    auto_compact_lag=auto_compact_lag,
+                    ops_first_round_only=ops_first_round_only,
+                    paged_inkernel=self._paged_inkernel,
+                    metrics=self.metrics,
+                    chaos=self.chaos,
+                    trace=self.trace,
+                    paged=self.paged,
+                )
         self.state, self.fab = res[0], res[1]
         i = 2
         if self.metrics is not None:
@@ -2810,26 +2824,32 @@ class FusedCluster:
         _drain_reads discipline, testing/lockstep.py)."""
         import numpy as np
 
-        cnt = np.asarray(self.state.rs_count)
+        with self.stats.span("serve.drain_reads.wait"):
+            cnt = np.asarray(self.state.rs_count)
         if not cnt.any():
             return {}
-        ctx = np.asarray(self.state.rs_ctx)
-        # widen-at-read: rs_index may be diet-v2 packed (uint16, same
-        # absolute values); served indexes stay absolute int32
-        idx = np.asarray(self.state.rs_index).astype(np.int32)
-        out = {
-            int(lane): [
-                (int(ctx[lane, k]), int(idx[lane, k]))
-                for k in range(int(cnt[lane]))
-            ]
-            for lane in np.nonzero(cnt > 0)[0]
-        }
-        self.state = dataclasses.replace(
-            self.state,
-            rs_ctx=jnp.zeros_like(self.state.rs_ctx),
-            rs_index=jnp.zeros_like(self.state.rs_index),
-            rs_count=jnp.zeros_like(self.state.rs_count),
-        )
+        with self.stats.span("serve.drain_reads.decode"):
+            ctx = np.asarray(self.state.rs_ctx)
+            # widen-at-read: rs_index may be diet-v2 packed (uint16, same
+            # absolute values); served indexes stay absolute int32
+            idx = np.asarray(self.state.rs_index).astype(np.int32)
+            out = {
+                int(lane): [
+                    (int(ctx[lane, k]), int(idx[lane, k]))
+                    for k in range(int(cnt[lane]))
+                ]
+                for lane in np.nonzero(cnt > 0)[0]
+            }
+        # a .wait span: behind a served round's queue of block programs
+        # the reset's dispatch blocks until the device takes it (measured
+        # on a v5e, PERF.md §5), so this is device wait, not host work
+        with self.stats.span("serve.drain_reads.reset.wait"):
+            self.state = dataclasses.replace(
+                self.state,
+                rs_ctx=jnp.zeros_like(self.state.rs_ctx),
+                rs_index=jnp.zeros_like(self.state.rs_index),
+                rs_count=jnp.zeros_like(self.state.rs_count),
+            )
         return out
 
     def check_no_errors(self):

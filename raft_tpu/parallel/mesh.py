@@ -56,7 +56,6 @@ work unchanged on top.
 
 from __future__ import annotations
 
-import contextlib
 from collections import deque
 
 import jax
@@ -67,6 +66,7 @@ from raft_tpu.config import Shape
 from raft_tpu.ops.fused import FusedCluster, LocalOps
 from raft_tpu.parallel.sharded import ShardedFusedCluster
 from raft_tpu.scheduler import BlockPlan
+from raft_tpu.utils.profiling import StepStats
 
 
 class MeshBlockedCluster:
@@ -127,8 +127,8 @@ class MeshBlockedCluster:
             for i in range(self.k)
         ]
         self.lanes_per_shard = self.blocks[0].lanes_per_shard
-        # optional utils/profiling.py SpanRecorder (scheduler contract)
-        self.spans = None
+        # one span bag for the driver and its blocks (scheduler contract)
+        self.use_stats(StepStats())
         # hot/cold tiering (RAFT_TPU_TIER): per-block engines re-attached
         # with their contiguous slice of the logical id space (the
         # scheduler's exact partition), each keeping the sharded driver's
@@ -166,6 +166,12 @@ class MeshBlockedCluster:
         for r in recs:
             r["name"] = r["name"].replace("sharded.step.", "mesh.step.")
         return recs
+
+    def use_stats(self, stats: StepStats) -> None:
+        """Record the driver's and every block's host spans into `stats`."""
+        self.stats = stats
+        for b in self.blocks:
+            b.use_stats(stats)
 
     def prepare_ops(self, ops: LocalOps) -> list[LocalOps]:
         """Slice a global-lane LocalOps into K per-block bindings ONCE
@@ -224,12 +230,9 @@ class MeshBlockedCluster:
         if trace is not None:
             trace = self._check_streams(trace, "trace", "TraceStream")
         per_ops = self._bind_ops(ops)
-        sp = self.spans
         if self.k == 1:
             b = self.blocks[0]
-            with sp.span("dispatch", block=0, rounds=rounds) if sp else (
-                contextlib.nullcontext()
-            ):
+            with self.stats.span("sched.dispatch", block=0, rounds=rounds):
                 b.run(
                     rounds,
                     ops=None if per_ops is None else per_ops[0],
@@ -245,9 +248,9 @@ class MeshBlockedCluster:
         for step, first, last in self.plan.sweep(rounds):
             for i, b in enumerate(self.blocks):
                 o = per_ops[i] if (per_ops is not None and first) else None
-                with sp.span("dispatch", block=i, round=done, rounds=step) if (
-                    sp
-                ) else contextlib.nullcontext():
+                with self.stats.span(
+                    "sched.dispatch", block=i, round=done, rounds=step
+                ):
                     b.run(
                         step,
                         ops=o,
@@ -302,6 +305,7 @@ class MeshBlockedCluster:
                 sink=None if sink is None else (
                     lambda s, seq, b, i=i: sink(i, s, seq, b)
                 ),
+                stats=self.stats,
             )
             for i in range(self.k)
         ]

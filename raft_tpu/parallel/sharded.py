@@ -820,9 +820,11 @@ class ShardedFusedCluster:
                 engine, rounds, do_tick, auto_propose, auto_compact_lag,
                 rpc, tile, interp,
             )
-        res = self._cache[key](
-            self.inner.state, self.inner.fab, ops, self.inner.mute, *extras,
-        )
+        with self.inner.stats.span("sched.enqueue"):
+            res = self._cache[key](
+                self.inner.state, self.inner.fab, ops, self.inner.mute,
+                *extras,
+            )
         self.inner.state, self.inner.fab = res[0], res[1]
         j = 2
         if has_met:

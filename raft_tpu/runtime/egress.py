@@ -47,6 +47,7 @@ from typing import NamedTuple
 import numpy as np
 
 from raft_tpu.ops import ready_mask
+from raft_tpu.utils.profiling import StepStats
 
 
 class EgressView(NamedTuple):
@@ -86,11 +87,16 @@ def shard_egress_view(state, lo: int, hi: int) -> EgressView:
 
 
 class EgressStream:
-    def __init__(self, sink=None):
+    """`stats` (a utils/profiling StepStats, a private one by default)
+    takes the egress.resolve.wait span — the blocking host copy of a
+    bundle — and the egress.sink span around each sink call."""
+
+    def __init__(self, sink=None, stats=None):
         self.enabled = ready_mask.egress_enabled()
         self._pending = None  # (block_id, device DeltaBundle)
         self._prev = None  # resolved PrevCursors of the last pushed block
         self.sink = sink
+        self.stats = StepStats() if stats is None else stats
         self.blocks = 0
         self.lanes_scanned = 0  # N per pushed block (what a scalar poll pays)
         self.lanes_active = 0  # sum of per-block active counts
@@ -122,9 +128,10 @@ class EgressStream:
             return
         block_id, dev = self._pending
         self._pending = None
-        bundle = ready_mask.DeltaBundle(
-            *(None if a is None else np.asarray(a) for a in dev)
-        )
+        with self.stats.span("egress.resolve.wait"):
+            bundle = ready_mask.DeltaBundle(
+                *(None if a is None else np.asarray(a) for a in dev)
+            )
         self._prev = ready_mask.PrevCursors(
             term=bundle.term, lead=bundle.lead, state=bundle.state,
             committed=bundle.committed, applied=bundle.applied,
@@ -134,7 +141,8 @@ class EgressStream:
         self.lanes_scanned += int(bundle.changed.shape[0])
         self.lanes_active += int(bundle.count)
         if self.sink is not None:
-            self.sink(block_id, bundle)
+            with self.stats.span("egress.sink"):
+                self.sink(block_id, bundle)
 
 
 class ShardedEgressStream:
@@ -150,14 +158,15 @@ class ShardedEgressStream:
     sink(shard, block_id, DeltaBundle) fires per shard in shard order."""
 
     def __init__(self, n_shards: int, lanes_per_shard: int | None = None,
-                 sink=None):
+                 sink=None, stats=None):
         self.n_shards = n_shards
         self.lanes_per_shard = lanes_per_shard
         self.streams = [
             EgressStream(
                 sink=None if sink is None else (
                     lambda bid, b, s=s: sink(s, bid, b)
-                )
+                ),
+                stats=stats,
             )
             for s in range(n_shards)
         ]

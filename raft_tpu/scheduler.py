@@ -43,7 +43,6 @@ that need bounded device-queue memory (None = unbounded, pure async).
 
 from __future__ import annotations
 
-import contextlib
 from collections import deque
 
 import jax
@@ -52,6 +51,7 @@ import numpy as np
 
 from raft_tpu.config import Shape
 from raft_tpu.ops.fused import _SCAN_UNROLL, FusedCluster, LocalOps
+from raft_tpu.utils.profiling import StepStats
 
 
 class BlockPlan:
@@ -254,11 +254,11 @@ class BlockedFusedCluster:
             )
             for i in range(self.k)
         ]
-        # optional utils/profiling.py SpanRecorder: when set, every block
-        # dispatch records a (name, t0, dur, labels) span the trace
-        # assembler folds into the Perfetto timeline (host dispatch time —
-        # JAX async dispatch means device execution rides behind it)
-        self.spans = None
+        # one span bag for the driver and its blocks: a sched.dispatch span
+        # per block dispatch (host dispatch time — JAX async dispatch means
+        # device execution rides behind it) around each block's
+        # sched.enqueue; a ServeLoop hands in its own through use_stats
+        self.use_stats(StepStats())
         # hot/cold tiering (RAFT_TPU_TIER, raft_tpu/tier/): re-attach each
         # block's engine with its slice of the LOGICAL id space — a
         # contiguous equal partition, so L == G is lane-identical to the
@@ -285,6 +285,12 @@ class BlockedFusedCluster:
             )
 
     # -- driving ----------------------------------------------------------
+
+    def use_stats(self, stats: StepStats) -> None:
+        """Record the driver's and every block's host spans into `stats`."""
+        self.stats = stats
+        for b in self.blocks:
+            b.use_stats(stats)
 
     def prepare_ops(self, ops: LocalOps) -> list[LocalOps]:
         """Slice a global-lane LocalOps into K per-block bindings ONCE.
@@ -337,14 +343,11 @@ class BlockedFusedCluster:
             trace = self._check_streams(trace, "trace", "TraceStream")
         per_ops = self._bind_ops(ops)
         ops_first = kw.get("ops_first_round_only", True)
-        sp = self.spans
         if self.k == 1:
             # one resident block: a single multi-round scan dispatch beats
             # any interleave (nothing to overlap with)
             b = self.blocks[0]
-            with sp.span("dispatch", block=0, rounds=rounds) if sp else (
-                contextlib.nullcontext()
-            ):
+            with self.stats.span("sched.dispatch", block=0, rounds=rounds):
                 b.run(
                     rounds,
                     ops=None if per_ops is None else per_ops[0],
@@ -361,9 +364,9 @@ class BlockedFusedCluster:
                 o = None
                 if per_ops is not None and (first or not ops_first):
                     o = per_ops[i]
-                with sp.span("dispatch", block=i, round=done, rounds=step) if (
-                    sp
-                ) else contextlib.nullcontext():
+                with self.stats.span(
+                    "sched.dispatch", block=i, round=done, rounds=step
+                ):
                     b.run(
                         step,
                         ops=o,

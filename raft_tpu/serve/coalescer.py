@@ -29,6 +29,7 @@ by the router.
 
 from __future__ import annotations
 
+import time
 from collections import deque
 from typing import Any
 
@@ -43,11 +44,16 @@ from raft_tpu.serve.kv import Command
 
 
 class ProposeTicket:
-    """One admitted mutation's future: propose -> commit -> notify."""
+    """One admitted mutation's future: propose -> commit -> notify.
+
+    Each round stamp has a `time.perf_counter()` twin: submit_t, inject_t
+    (the last injection: a re-proposal after an epoch resync re-stamps
+    it), release_t (the commit seen) and notify_t."""
 
     __slots__ = (
         "cmd", "group", "index", "submit_round", "inject_round",
         "commit_round", "notify_round", "done", "applied",
+        "submit_t", "inject_t", "release_t", "notify_t",
     )
 
     def __init__(self, cmd: Command, group: int, submit_round: int):
@@ -60,6 +66,10 @@ class ProposeTicket:
         self.notify_round = None
         self.done = False
         self.applied = None  # True = mutated KV, False = dedup collapsed
+        self.submit_t = time.perf_counter()
+        self.inject_t = None
+        self.release_t = None
+        self.notify_t = None
 
     @property
     def latency_rounds(self) -> int | None:
@@ -69,11 +79,15 @@ class ProposeTicket:
 
 
 class ReadTicket:
-    """One admitted linearizable GET's future."""
+    """One admitted linearizable GET's future. Wall-clock stamps: submit_t,
+    inject_t (its batch's ReadIndex ctx injected, or the lease route
+    taken; re-stamped when a batch is re-opened), release_t (the ReadIndex
+    released, or the lease validated) and notify_t."""
 
     __slots__ = (
         "session", "group", "key", "submit_round", "notify_round",
         "done", "value", "index",
+        "submit_t", "inject_t", "release_t", "notify_t",
     )
 
     def __init__(self, session: int, group: int, key: str, submit_round: int):
@@ -85,6 +99,10 @@ class ReadTicket:
         self.done = False
         self.value = None
         self.index = None  # the ReadIndex the answer reflects
+        self.submit_t = time.perf_counter()
+        self.inject_t = None
+        self.release_t = None
+        self.notify_t = None
 
 
 class ReadBatch:
@@ -135,6 +153,9 @@ class ProposalCoalescer:
         self._batches_of: dict[int, set] = {}
         self._next_ctx = 1
         self.on_read_retry = None  # optional hook (ServeLoop -> metrics)
+        # exact injection counts (ServeLoop.metrics_snapshot folds them)
+        self.entries_injected = 0
+        self.read_batches_injected = 0
         # lease fast-path hook (ServeLoop -> router.route_lease_reads,
         # wired only when the device lease plane is on): offered a group's
         # NEW waiting reads at build time; True = the router took them
@@ -237,6 +258,7 @@ class ProposalCoalescer:
         """
         prop_n = None  # allocated lazily: zero-op rounds build nothing
         injections = []
+        now = time.perf_counter()
         # iterate only the groups with queued/in-flight work — O(active),
         # never O(logical groups); sorted for deterministic injection order
         for g in sorted(self.active_groups()):
@@ -255,7 +277,9 @@ class ProposalCoalescer:
                 for t in batch:
                     t.index = view.next_index
                     t.inject_round = round_id
+                    t.inject_t = now
                     view.next_index += 1
+                self.entries_injected += m
                 prop_n[view.leader_lane] = m
                 prop_bytes[view.leader_lane] = self.cmd_bytes
                 injections.append((view, batch))
@@ -268,7 +292,7 @@ class ProposalCoalescer:
                 # fast path — already-open ReadIndex batches still retry
                 # through _pick_read_ctx below
                 self.read_wait.pop(g)
-            ctx = self._pick_read_ctx(g, view, round_id)
+            ctx = self._pick_read_ctx(g, view, round_id, now)
             if ctx:
                 if prop_n is None:
                     prop_n = np.zeros((self.n,), np.int32)
@@ -284,7 +308,7 @@ class ProposalCoalescer:
         )
         return ops, injections
 
-    def _pick_read_ctx(self, g: int, view, round_id: int) -> int:
+    def _pick_read_ctx(self, g: int, view, round_id: int, now: float) -> int:
         """One read_ctx slot per lane per round: a due retry of the oldest
         unreleased batch wins over opening a new batch."""
         due = [
@@ -308,7 +332,10 @@ class ProposalCoalescer:
             # a colliding live ticket
             self._next_ctx = 1 if self._next_ctx >= (1 << 30) else ctx + 1
             b = ReadBatch(ctx, g, self.read_wait.pop(g), round_id)
+            for t in b.tickets:
+                t.inject_t = now
             self.read_batches[ctx] = b
             self._batches(g).add(ctx)
+            self.read_batches_injected += 1
             return ctx
         return 0

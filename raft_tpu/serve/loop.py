@@ -20,6 +20,17 @@ on the hot path: commit discovery rides the O(active) egress bundles, and
 the only synchronous pulls are the read drain (gated on outstanding
 reads) and epoch resyncs (gated on observed leader changes).
 
+Measurement: each phase above is a `StepStats.span` (utils/profiling.py)
+in the loop's one bag, shared with the cluster's per-block dispatch and
+the egress streams — `serve.admission`, `serve.coalesce`,
+`serve.dispatch` (around `sched.dispatch` > `sched.enqueue`,
+`egress.resolve.wait`, `egress.sink`), `serve.drain_reads` (its
+`.wait` / `.decode` / `.reset.wait` per block), `serve.resync` (its
+`.wait`) — each also a jax.profiler annotation on the device trace's
+clock. Tickets carry perf_counter stamps beside their round stamps, and
+`metrics_snapshot()` adds the flow counts and the wall-clock stage
+histograms.
+
 Clock: rounds ARE ticks (do_tick=True — the engine's 1-round = 1-tick
 contract), so `self.round` is simultaneously the latency clock, the lease
 clock, and the device election clock. Bootstrap rounds count.
@@ -30,8 +41,6 @@ and never raised — callers route on them; every one is counted under
 """
 
 from __future__ import annotations
-
-import contextlib
 
 from raft_tpu.metrics.host import (
     HostCounters,
@@ -67,6 +76,20 @@ from raft_tpu.trace import device as trdev
 from raft_tpu.utils.profiling import SpanRecorder, StepStats
 
 
+# log-spaced (1-2-5) edges of the wall-clock stage histograms, in ms
+STAGE_EDGES_MS = (
+    1, 2, 5, 10, 20, 50, 100, 200, 500,
+    1000, 2000, 5000, 10000, 20000, 50000, 100000,
+)
+# a request's wall-clock stages, between its tickets' perf_counter stamps
+# submit_t -> inject_t -> release_t -> notify_t; release is the ReadIndex
+# release (or lease validation) for a get and the commit seen for a put
+STAGES = {
+    "get": ("get_queue_ms", "get_readindex_ms", "get_apply_ms"),
+    "put": ("put_queue_ms", "put_commit_ms", "put_apply_ms"),
+}
+
+
 class ServeMetrics:
     """The serving plane's own registry: host counters + the notify
     latency histogram (device-round edges). Deliberately NOT merged into
@@ -81,7 +104,20 @@ class ServeMetrics:
         # the lease plane's read win behind the proposal pipeline's 2-3
         # round floor (benches/serve_bench.py reports both percentiles)
         self.read_hist = HostHistogram()
+        # the same latencies in wall time, one histogram per stage
+        self.stage_hists = {
+            name: HostHistogram(STAGE_EDGES_MS)
+            for names in STAGES.values() for name in names
+        }
         self.rounds = 0
+
+    def observe_stages(self, kind: str, t) -> None:
+        """File a notified ticket's queue / readindex-or-commit / apply
+        wall times (ms) under `kind` ("get" or "put")."""
+        queue, middle, apply = (self.stage_hists[n] for n in STAGES[kind])
+        queue.observe(1e3 * (t.inject_t - t.submit_t))
+        middle.observe(1e3 * (t.release_t - t.inject_t))
+        apply.observe(1e3 * (t.notify_t - t.release_t))
 
     def snapshot(self) -> dict:
         # the stamped hist_name lets merge_snapshots namespace this family
@@ -96,6 +132,7 @@ class ServeMetrics:
             "hists": {
                 "notify_latency_rounds": self.hist.snapshot(),
                 "read_notify_latency_rounds": self.read_hist.snapshot(),
+                **{n: h.snapshot() for n, h in self.stage_hists.items()},
             },
             "rounds": int(self.rounds),
         }
@@ -162,18 +199,20 @@ class ServeLoop:
         )
 
         self.metrics = ServeMetrics()
-        # host-side phase timings for the round loop (admission / coalesce
-        # / dispatch / drain_reads / resync), exported as step_* counters
-        # through the registry so one Prometheus scrape covers the serving
-        # counters AND where the host spends its wall time
-        self.stats = StepStats()
         # host span log for the trace assembler; gated on the flight
-        # recorder so the span list (and the per-phase TraceAnnotations)
-        # cost nothing on untraced production loops
+        # recorder so the span list does not grow on untraced loops
         self.spans = SpanRecorder() if trdev.tracelog_enabled() else None
+        # one span bag for the round loop (serve.*), the cluster's per-block
+        # dispatch (sched.*) and the egress streams (egress.*): exported as
+        # step_* counters through the registry so one Prometheus scrape
+        # covers the serving counters AND where the host spends its wall
+        # time; every span is also a jax.profiler annotation
+        self.stats = StepStats("serve", spans=self.spans)
+        cluster.use_stats(self.stats)
         self.registry = MetricsRegistry()
         self.registry.register("serve", self.metrics.snapshot)
         self.registry.register("steps", self.stats.snapshot)
+        self.registry.register("flow", self._flow_snapshot)
         self.sessions = SessionManager(self.logical_groups)
         self.kv = KVStore(self.logical_groups)
         self.admission = AdmissionController(
@@ -220,7 +259,8 @@ class ServeLoop:
             EgressStream(
                 sink=lambda seq, bundle, bi=i: self.router.on_bundle(
                     bi, seq, bundle
-                )
+                ),
+                stats=self.stats,
             )
             for i in range(self.k)
         ]
@@ -410,7 +450,6 @@ class ServeLoop:
         self.round += 1
         self.metrics.rounds = self.round
         self.router.round = self.round
-        sp = self.spans
         if self.tier is not None:
             self.tier.tick(self.round)
             if self.tier.pending():
@@ -418,20 +457,16 @@ class ServeLoop:
                 # (attribution parks with the cold record's exact rows);
                 # admissions re-attach through the resync machinery below
                 # — the restored leader re-attaches the same round
-                with self.stats.timed("tier"):
+                with self.stats.span("serve.tier"):
                     evicted, admitted = self.tier.apply(self.round)
                 for g in evicted:
                     self.router.views[g].detach()
                     self.router.needs_resync.discard(g)
                 for g in admitted:
                     self.router.needs_resync.add(g)
-        with self.stats.timed("admission"):
+        with self.stats.span("serve.admission"):
             self.admission.tick()
-        with self.stats.timed("coalesce"), (
-            sp.span("inject", round=self.round)
-            if sp
-            else contextlib.nullcontext()
-        ):
+        with self.stats.span("serve.coalesce", round=self.round):
             ops, injections = self.coalescer.build(
                 self.router.views, self.round
             )
@@ -440,11 +475,7 @@ class ServeLoop:
                 # slice once, explicitly — the scheduler's identity LRU
                 # cannot hit on a fresh per-round ops object
                 ops = self.cluster.prepare_ops(ops)
-        with self.stats.timed("dispatch"), (
-            sp.span("dispatch", round=self.round)
-            if sp
-            else contextlib.nullcontext()
-        ):
+        with self.stats.span("serve.dispatch", round=self.round):
             self.cluster.run(
                 1,
                 ops=ops,
@@ -453,14 +484,16 @@ class ServeLoop:
                 auto_compact_lag=self.compact_lag,
             )
         if self.coalescer.outstanding_reads:
-            with self.stats.timed("drain_reads"):
+            with self.stats.span("serve.drain_reads"):
                 drained = self.cluster.drain_read_states()
                 for glane, rss in drained.items():
                     for ctx, index in rss:
                         self.router.on_read_release(glane, ctx, index)
         if self.router.needs_resync:
-            with self.stats.timed("resync"):
-                self.router.resync(self._columns())
+            with self.stats.span("serve.resync"):
+                with self.stats.span("serve.resync.wait"):
+                    columns = self._columns()
+                self.router.resync(columns)
         if self.expire_every and self.round % self.expire_every == 0:
             self.kv.expire(self.round)
         self.metrics.counters.set("sessions_active", self.sessions.active)
@@ -470,12 +503,7 @@ class ServeLoop:
         resolves bundles one round behind, so the final round's commits
         only notify after a flush. The flight-recorder streams drain on
         the same fence so `traces[i].events` is complete afterwards."""
-        sp = self.spans
-        with self.stats.timed("host_drain"), (
-            sp.span("host_drain", round=self.round)
-            if sp
-            else contextlib.nullcontext()
-        ):
+        with self.stats.span("serve.host_drain", round=self.round):
             for s in self.streams:
                 s.flush()
             if self.traces is not None:
@@ -515,9 +543,34 @@ class ServeLoop:
             self.logical_groups, self.router.applied_log, self.round
         )
 
+    def _flow_snapshot(self) -> dict:
+        """Exact counts at the serving path's boundaries: points where the
+        host blocks on the device (`syncs`, one per `.wait` span),
+        round-program executions enqueued (`dispatches`, one per
+        `sched.enqueue` span), the egress bundles' bytes and active lanes,
+        and the coalescer's injections (`read_batches_released` is the
+        router's counter)."""
+        counts = self.stats.counts
+        return {
+            "counters": {
+                "syncs": sum(
+                    n for k, n in counts.items() if k.endswith(".wait")
+                ),
+                "dispatches": counts.get("sched.enqueue", 0),
+                "egress_bytes": sum(s.bytes for s in self.streams),
+                "egress_lanes_active": sum(
+                    s.lanes_active for s in self.streams
+                ),
+                "entries_injected": self.coalescer.entries_injected,
+                "read_batches_injected": self.coalescer.read_batches_injected,
+            },
+            "rounds": 0,
+        }
+
     def metrics_snapshot(self) -> dict:
         """Merged host-plane snapshot: serving counters + notify-latency
-        histogram (namespaced by hist_name) + step_* phase timings."""
+        histograms in rounds and stage histograms in wall ms (namespaced
+        by name) + step_* span timings + the flow counts."""
         return self.registry.snapshot()
 
     def engine_snapshot(self) -> dict | None:

@@ -32,6 +32,8 @@ silently double-firing — the bench's acceptance gate asserts it stays 0.
 
 from __future__ import annotations
 
+import time
+
 import numpy as np
 
 from raft_tpu.metrics.host import LEASE_EVENTS
@@ -248,6 +250,7 @@ class CompletionRouter:
             if t is None:
                 continue  # not ours (election empty entry, pre-attach)
             t.commit_round = self.round
+            t.release_t = time.perf_counter()
             applied = self.kv.apply(view.gid, t.cmd, self.round)
             self.applied_log.append((view.gid, t.cmd, self.round))
             self._complete(t, applied)
@@ -259,10 +262,12 @@ class CompletionRouter:
             return
         t.applied = applied
         t.notify_round = self.round
+        t.notify_t = time.perf_counter()
         t.done = True
         self.admission.release()
         self.metrics.counters.inc("proposals_notified")
         self.metrics.hist.observe(self.round - t.submit_round)
+        self.metrics.observe_stages("put", t)
         if self.lifecycle is not None:
             self.lifecycle.append((
                 t.group, t.submit_round, t.inject_round,
@@ -295,6 +300,9 @@ class CompletionRouter:
             or not bool(ok[local])
         ):
             return False
+        now = time.perf_counter()
+        for t in tickets:
+            t.inject_t = now
         self.lease_pending.setdefault(view.gid, []).append(
             (list(tickets), view.term, int(epoch[local]))
         )
@@ -337,6 +345,7 @@ class CompletionRouter:
                     self.coalescer._read_wait(gid).extend(tickets)
                     self._count_lease("lease_reads_fallback", gid, len(tickets))
                     continue
+                self._released(tickets)
                 self._served_batches.append(
                     (ReadBatch(0, gid, tickets, self.round), index)
                 )
@@ -358,13 +367,21 @@ class CompletionRouter:
         batch = self.coalescer.take_batch(ctx)
         if batch is None:
             return
+        self.metrics.counters.inc("read_batches_released")
         view = self.views[batch.group]
         if glane != view.leader_lane:
             # released by a lane we no longer trust; re-batch the tickets
             self.coalescer._read_wait(batch.group).extend(batch.tickets)
             return
+        self._released(batch.tickets)
         self._served_batches.append((batch, index))
         self._serve_ready_batches()
+
+    @staticmethod
+    def _released(tickets) -> None:
+        now = time.perf_counter()
+        for rt in tickets:
+            rt.release_t = now
 
     def _serve_ready_batches(self) -> None:
         still = []
@@ -384,10 +401,12 @@ class CompletionRouter:
         rt.value = self.kv.get(rt.group, rt.key, self.round)
         rt.index = index
         rt.notify_round = self.round
+        rt.notify_t = time.perf_counter()
         rt.done = True
         self.admission.release()
         self.metrics.counters.inc("reads_served")
         self.metrics.read_hist.observe(self.round - rt.submit_round)
+        self.metrics.observe_stages("get", rt)
 
     @property
     def reads_waiting_apply(self) -> int:
@@ -431,6 +450,7 @@ class CompletionRouter:
             for t in survivors:
                 t.index = None
                 t.inject_round = None
+                t.inject_t = None
             self.coalescer.requeue_front(gid, survivors)
             for rt in self.coalescer.drop_group_reads(gid):
                 self.coalescer._read_wait(gid).append(rt)

@@ -6,9 +6,15 @@ three observability planes this repo grows —
                        arg) rows; placed on the ROUND axis (ts = round *
                        round_us) as instant events, one track per lane
   host round spans     utils/profiling.py SpanRecorder tuples from the
-                       blocked scheduler ("dispatch" per block/round) and
-                       ServeLoop ("inject"/"dispatch"/"host_drain"); placed
-                       on the WALL-CLOCK axis
+                       ServeLoop's StepStats.span calls ("serve.coalesce",
+                       "serve.dispatch", "serve.drain_reads" and its
+                       ".wait" / ".decode" / ".reset.wait",
+                       "serve.host_drain", ...), the blocked scheduler's
+                       "sched.dispatch" / "sched.enqueue" per block and
+                       round, and the egress streams' "egress.resolve.wait"
+                       / "egress.sink"; placed on the WALL-CLOCK axis (the
+                       same spans are jax.profiler annotations, always
+                       emitted, free without a profiler session)
   proposal lifecycle   serve/router.py CompletionRouter.lifecycle tuples
                        (group, submit, inject, commit, notify); rendered as
                        stacked queued -> replicating -> notify_lag slices

@@ -1,16 +1,27 @@
-"""Tracing/profiling hooks (SURVEY §5: the reference ships none — "add its
-own (jax.profiler traces), no parity required").
+"""Host-side spans for the serving path, on the profiler's clock (SURVEY §5:
+the reference ships no tracing — "add its own (jax.profiler traces), no
+parity required").
 
-Two layers:
-  - `trace(dir)` — capture an XLA/TPU profile of a code region into a
-    TensorBoard-loadable directory (jax.profiler.trace), with named
-    sub-regions via `annotate`.
-  - `StepStats` — cheap host-side counters for the serving path (the analog
-    of the reference's MemoryStorage.callStats, storage.go:92-94, which
-    feeds BenchmarkRawNode's storage-access metrics, rawnode_test.go:1244).
+`StepStats.span(name)` is the one span primitive. One call:
 
-Env integration: benchmarks honor RAFT_TPU_TRACE=<dir> (see bench.py) so
-the driver can turn any run into a profile without code changes.
+  - adds the region's seconds and count to the StepStats bag — the analog
+    of the reference's MemoryStorage.callStats (storage.go:92-94, which
+    feeds BenchmarkRawNode's storage-access metrics, rawnode_test.go:1244)
+    — exported as the `step_<key>_count` / `step_<key>_micros` counters;
+  - always opens a `jax.profiler.TraceAnnotation` named `<layer>.<phase>`.
+    With no profiler session open it costs well under a microsecond; under
+    `jax.profiler.trace` the span lands on the host plane, on the same
+    clock as the device's operations;
+  - appends `(name, t0, dur_s, labels)` to an attached SpanRecorder, the
+    trace assembler's host-span list (opt-in: ServeLoop attaches one only
+    under RAFT_TPU_TRACELOG=1).
+
+Span names are stable, because metrics read them: `serve.*` for the
+ServeLoop's phases, `sched.*` for per-block dispatch, `egress.*` for the
+egress stream and its sink, `node.*` for NodeHost's operations. A child
+ending in `.wait` covers one point where the host blocks on the device (a
+device->host pull, or a dispatch held back by the device's queue), so a
+layer's self time is its span minus the `.wait` spans inside it.
 """
 
 from __future__ import annotations
@@ -18,58 +29,48 @@ from __future__ import annotations
 import contextlib
 import time
 
-from raft_tpu import config
-
-
-@contextlib.contextmanager
-def trace(log_dir: str | None = None):
-    """Profile the enclosed region. No-op when log_dir is None/empty, so
-    call sites can pass env_trace_dir() unconditionally."""
-    if not log_dir:
-        yield
-        return
-    import jax
-
-    with jax.profiler.trace(log_dir):
-        yield
-
-
-def annotate(name: str):
-    """Named sub-region inside a trace (shows as a TraceAnnotation row)."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)
+from jax.profiler import TraceAnnotation
 
 
 class StepStats:
-    """Host-side op counters + wall timings for the RawNode serving path.
+    """Host-side span counts + wall seconds, keyed by span name.
 
-    Attach with `RawNodeBatch.trace_stats = StepStats()`? No — counting
-    happens at the call sites the app owns; this is a plain bag:
+    A bag owns one layer: its own spans are keyed without the layer prefix
+    (a `StepStats("serve")` keys `serve.coalesce` as `coalesce` and
+    `serve.drain_reads.wait` as `drain_reads.wait`), while spans of the
+    layers below it that it is shared with keep their full dotted names
+    (`sched.enqueue`, `egress.sink`):
 
-        stats = StepStats()
-        with stats.timed("step"):
-            batch.step(lane, msg)
+        stats = StepStats("serve")
+        with stats.span("serve.coalesce"):
+            ...
         print(stats.as_dict())
     """
 
-    def __init__(self):
+    def __init__(self, layer: str | None = None, spans=None):
         self.counts: dict[str, int] = {}
         self.seconds: dict[str, float] = {}
+        self._own = f"{layer}." if layer else ""
+        self.spans = spans  # optional SpanRecorder
 
-    def bump(self, key: str, n: int = 1):
-        self.counts[key] = self.counts.get(key, 0) + n
+    def key(self, name: str) -> str:
+        if self._own and name.startswith(self._own):
+            return name[len(self._own):]
+        return name
 
     @contextlib.contextmanager
-    def timed(self, key: str):
+    def span(self, name: str, **labels):
+        key = self.key(name)
         t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.seconds[key] = self.seconds.get(key, 0.0) + (
-                time.perf_counter() - t0
-            )
-            self.bump(key)
+        with TraceAnnotation(name):
+            try:
+                yield
+            finally:
+                dt = time.perf_counter() - t0
+                self.seconds[key] = self.seconds.get(key, 0.0) + dt
+                self.counts[key] = self.counts.get(key, 0) + 1
+                if self.spans is not None:
+                    self.spans.spans.append((name, t0, dt, labels))
 
     def as_dict(self) -> dict:
         return {
@@ -82,44 +83,30 @@ class StepStats:
 
     def snapshot(self) -> dict:
         """Metrics-plane snapshot (registerable with MetricsRegistry): each
-        timed key becomes step_<key>_count / step_<key>_micros counters.
-        Monotone like every other counter source, so registry deltas and
-        Prometheus scrapes work unchanged."""
+        key becomes step_<key>_count / step_<key>_micros counters, dots as
+        underscores (Prometheus names). Monotone like every other counter
+        source, so registry deltas and Prometheus scrapes work unchanged."""
         counters: dict[str, int] = {}
         for k in sorted(set(self.counts) | set(self.seconds)):
-            counters[f"step_{k}_count"] = self.counts.get(k, 0)
-            counters[f"step_{k}_micros"] = int(self.seconds.get(k, 0.0) * 1e6)
+            name = k.replace(".", "_")
+            counters[f"step_{name}_count"] = self.counts.get(k, 0)
+            counters[f"step_{name}_micros"] = int(
+                self.seconds.get(k, 0.0) * 1e6
+            )
         return {"counters": counters, "rounds": 0}
 
 
 class SpanRecorder:
-    """Host-side span log for the trace assembler (trace/assemble.py):
-    each span() region records (name, t0, dur_s, labels) AND mirrors into
-    a jax.profiler TraceAnnotation so the same markup shows up in XLA
-    profiles captured with trace(). Used by the blocked scheduler for
-    per-(block, round) dispatch phases and by ServeLoop for the serving
-    phases (inject / dispatch / egress_drain / host_drain)."""
+    """Host-side span log for the trace assembler (trace/assemble.py): a
+    StepStats with a recorder attached appends one (name, t0, dur_s,
+    labels) tuple per span; the tier and the fabric driver append their
+    point events (tier_evict / fabric_tx ...) to the same list."""
 
     def __init__(self):
         self.spans: list[tuple[str, float, float, dict]] = []
 
-    @contextlib.contextmanager
-    def span(self, name: str, **labels):
-        t0 = time.perf_counter()
-        with annotate(name):
-            try:
-                yield
-            finally:
-                self.spans.append(
-                    (name, t0, time.perf_counter() - t0, labels)
-                )
-
     def clear(self):
         self.spans = []
-
-
-def env_trace_dir() -> str | None:
-    return config.env_raw("RAFT_TPU_TRACE") or None
 
 
 def live_buffer_bytes() -> int:

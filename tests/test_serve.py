@@ -397,6 +397,111 @@ def test_blocked_serving_round_trip(blocked_loop):
     assert sl.digest() == sl.twin_digest()
 
 
+def test_blocked_round_spans_counters_and_stage_stamps(blocked_loop):
+    """Served rounds up to the first ReadIndex release, then a flush, with
+    a SpanRecorder attached: span names and nesting, dotted StepStats
+    children within their parent, exact flow counts, and monotone ticket
+    stamps whose wall-clock stage histograms count every answered
+    request."""
+    from raft_tpu.metrics.host import prometheus_text
+    from raft_tpu.utils.profiling import SpanRecorder
+
+    sl = blocked_loop
+    k = sl.k
+    ss = [sl.open_session(f"sp{i}") for i in range(4)]
+    c0 = sl.metrics_snapshot()["counters"]
+    puts = [sl.put(s, f"{s.tenant}/k", s.tenant) for s in ss]
+    assert sl.drain(300)
+    c1 = sl.metrics_snapshot()["counters"]
+    assert c1["entries_injected"] - c0["entries_injected"] == len(puts)
+    gets = [sl.get(s, f"{s.tenant}/k") for s in ss]
+    rec = sl.stats.spans = SpanRecorder()
+    rounds = 0
+    try:
+        while rounds < 2 or not any(
+            sp[0] == "serve.drain_reads.reset.wait" for sp in rec.spans
+        ):
+            assert rounds < 16, "no ReadIndex release drained"
+            sl.step()
+            rounds += 1
+        sl.flush()
+    finally:
+        sl.stats.spans = None
+    spans = rec.spans
+    names = [sp[0] for sp in spans]
+    assert {
+        "serve.admission", "serve.coalesce", "serve.dispatch",
+        "sched.dispatch", "sched.enqueue", "egress.resolve.wait",
+        "egress.sink", "serve.drain_reads", "serve.drain_reads.wait",
+        "serve.drain_reads.decode", "serve.drain_reads.reset.wait",
+        "serve.host_drain",
+    } <= set(names)
+
+    def inside(child, parents):
+        _, t0, dt, _ = child
+        return any(
+            p[1] <= t0 and t0 + dt <= p[1] + p[2] + 1e-9
+            for p in spans if p[0] in parents
+        )
+
+    nest = {
+        "sched.dispatch": {"serve.dispatch"},
+        "sched.enqueue": {"sched.dispatch"},
+        "egress.resolve.wait": {"sched.dispatch", "serve.host_drain"},
+        "egress.sink": {"sched.dispatch", "serve.host_drain"},
+        "serve.drain_reads.wait": {"serve.drain_reads"},
+        "serve.drain_reads.decode": {"serve.drain_reads"},
+        "serve.drain_reads.reset.wait": {"serve.drain_reads"},
+    }
+    for sp in spans:
+        if sp[0] in nest:
+            assert inside(sp, nest[sp[0]]), sp
+    assert names.count("sched.enqueue") == rounds * k
+    assert names.count("serve.drain_reads.wait") == k * names.count(
+        "serve.drain_reads"
+    )
+    st = sl.stats
+    children = ("wait", "decode", "reset.wait")
+    assert sum(st.seconds[f"drain_reads.{c}"] for c in children) <= (
+        st.seconds["drain_reads"]
+    )
+    c2 = sl.metrics_snapshot()["counters"]
+    assert c2["dispatches"] - c1["dispatches"] == rounds * k
+    # each round's egress bundle resolves in the next round's push, the
+    # last one in the flush: one pull per block per round; per drain one
+    # pull per block, and one reset per block that held releases; one
+    # pull per resync
+    assert names.count("egress.resolve.wait") == rounds * k
+    assert c2["syncs"] - c1["syncs"] == (
+        rounds * k + k * names.count("serve.drain_reads")
+        + names.count("serve.drain_reads.reset.wait")
+        + names.count("serve.resync")
+    )
+    assert names.count("serve.drain_reads.reset.wait") == names.count(
+        "serve.drain_reads.decode"
+    )
+    assert c2["egress_bytes"] > c1["egress_bytes"]
+    assert sl.drain(300)
+    c3 = sl.metrics_snapshot()
+    cnt, hists = c3["counters"], c3["hists"]
+    groups = len({s.group for s in ss})
+    for name in ("read_batches_injected", "read_batches_released"):
+        assert cnt[name] - c1.get(name, 0) == groups, name
+    assert cnt["entries_injected"] == c1["entries_injected"]
+    for t in puts + gets:
+        assert t.done
+        assert t.submit_t <= t.inject_t <= t.release_t <= t.notify_t
+    for name in ("get_queue_ms", "get_readindex_ms", "get_apply_ms"):
+        assert hists[name]["count"] == cnt["reads_served"]
+    for name in ("put_queue_ms", "put_commit_ms", "put_apply_ms"):
+        assert hists[name]["count"] == cnt["proposals_notified"]
+    # the scrape the HTTP endpoint serves (serve/http.py) carries both
+    text = prometheus_text(c3, prefix="raft_tpu_serve")
+    assert f"raft_tpu_serve_syncs_total {cnt['syncs']}" in text
+    assert 'raft_tpu_serve_get_readindex_ms_bucket{le="+Inf"}' in text
+    assert "raft_tpu_serve_put_commit_ms_count" in text
+
+
 def test_blocked_no_leader_gate_before_bootstrap():
     from raft_tpu.scheduler import BlockedFusedCluster
 

@@ -464,9 +464,15 @@ def test_serve_loop_traces_lifecycle_and_assembles(monkeypatch):
     assert snap["hists"]["notify_latency_rounds"]["count"] >= 4
     assert snap["counters"]["proposals_notified"] >= 4
 
-    # spans recorded (gated on the recorder being enabled by TRACELOG)
+    # spans recorded (gated on the recorder being enabled by TRACELOG):
+    # the loop's phases, the cluster's jit call and the egress stream's
+    # wait and sink, all into the loop's one StepStats bag
     names = {s0 for s0, _, _, _ in loop.spans.spans}
-    assert {"inject", "dispatch"} <= names
+    assert {
+        "serve.coalesce", "serve.dispatch", "serve.host_drain",
+        "sched.enqueue", "egress.resolve.wait", "egress.sink",
+    } <= names
+    assert loop.stats.counts["sched.enqueue"] == snap["counters"]["dispatches"]
 
     # one Perfetto document from all three planes; it must round-trip
     # json and contain all three process tracks
@@ -506,12 +512,21 @@ def test_step_stats_snapshot_schema():
     from raft_tpu.utils.profiling import StepStats
 
     st = StepStats()
-    with st.timed("tick"):
+    with st.span("tick"):
         pass
     snap = st.snapshot()
     assert snap["counters"]["step_tick_count"] == 1
     assert "step_tick_micros" in snap["counters"]
     assert "hist" not in snap  # must not pollute merged histograms
+    # a layer's bag keys its own spans without the prefix and the shared
+    # ones in full; dots become underscores in the counter names
+    st = StepStats("serve")
+    with st.span("serve.drain_reads.wait"), st.span("egress.sink"):
+        pass
+    assert set(st.counts) == {"drain_reads.wait", "egress.sink"}
+    assert {"step_drain_reads_wait_count", "step_egress_sink_micros"} <= set(
+        st.snapshot()["counters"]
+    )
 
 
 def test_node_host_stats_time_loop_ops():
