@@ -60,11 +60,13 @@ _README_ROW_RE = re.compile(r"^\|\s*`(RAFT_TPU_[A-Z0-9_]+)`", re.MULTILINE)
 
 # rule C scope: module path (repo-relative) -> allowlisted functions.
 # These are the stream/bundle RESOLVE points where a host copy of device
-# data is the whole job; bridge.py (state reconstruction) and the device
-# planes themselves are out of scope by design.
+# data is the whole job, and the op expansion's jitted body; bridge.py
+# (state reconstruction) and the device planes themselves are out of
+# scope by design.
 HOST_PLANE_ALLOW = {
     "raft_tpu/serve/router.py": {"on_bundle"},
     "raft_tpu/serve/loop.py": set(),
+    "raft_tpu/serve/inject.py": {"_expand"},
     "raft_tpu/runtime/wal.py": {"_resolve"},
     "raft_tpu/runtime/egress.py": {"_resolve_pending", "merge_delta_bundles"},
     "raft_tpu/runtime/trace.py": {"_resolve_pending"},

@@ -5,7 +5,8 @@ Layers, client to device:
 
   session.py    per-tenant sessions, dedup seq, static hash placement
   admission.py  token buckets + in-flight cap -> typed Rejected(reason)
-  coalescer.py  client queues -> ONE LocalOps injection per round/block
+  coalescer.py  client queues -> ONE record of the round's touched lanes
+  inject.py     that record -> per-block LocalOps, expanded on the device
   router.py     egress bundles -> commit watermarks -> KV apply -> notify
   kv.py         host-side applied materialization + scalar twin replay
   loop.py       ServeLoop: the per-round pipeline over a (Blocked)FusedCluster

@@ -1,11 +1,13 @@
 """Proposal coalescer: everything clients submitted between rounds becomes
-ONE batched LocalOps injection per round (per block, via the blocked
-scheduler's prepare_ops path).
+ONE batched op injection per round: a RoundOps record of the lanes it
+touched, which serve/inject.py expands into the per-block LocalOps on
+the device in one dispatch.
 
 The Podracer shape (PAPERS.md, arxiv 2104.06272): the device runs rounds
 back-to-back; the host's only hot-path job is to fold the client queues
-into the next round's [N] op columns. Per group, per round, the coalescer
-injects at most
+into the next round's ops, in O(active) work: the [N] op columns are
+never built on the host. Per group, per round, the coalescer injects at
+most
 
   min(queue depth, Shape.max_msg_entries, window budget)
 
@@ -19,8 +21,8 @@ past the per-round cap simply wait in the (bounded) queue, and the bound
 surfaces as a typed Rejected(queue_full) at admission.
 
 Linearizable GETs batch harder: all reads for a group waiting at round r
-share ONE ReadIndex ticket (the [N] read_ctx column carries one ctx per
-lane per round — the etcd read-batching shape). A batch whose release
+share ONE ReadIndex ticket (the read_ctx column carries one ctx per lane
+per round — the etcd read-batching shape). A batch whose release
 never arrives (dropped beat under chaos, ro-ring overflow, leader not yet
 committed in its term) is re-injected with the SAME ctx after
 read_retry_rounds; reads are idempotent, so a double release is ignored
@@ -31,7 +33,7 @@ from __future__ import annotations
 
 import time
 from collections import deque
-from typing import Any
+from typing import Any, NamedTuple
 
 import numpy as np
 
@@ -41,6 +43,18 @@ from raft_tpu.serve.admission import (
     Rejected,
 )
 from raft_tpu.serve.kv import Command
+
+
+class RoundOps(NamedTuple):
+    """One round's ops, one entry per touched lane (global lane order of
+    the cluster): a lane that gets both a proposal and a read ctx in the
+    round is one entry. Four [n] int32 columns; every other LocalOps
+    column is zero on a served round."""
+
+    lane: np.ndarray  # global lane (the group's leader lane)
+    prop_n: np.ndarray  # entries proposed this round (0 = none)
+    prop_bytes: np.ndarray  # payload size per entry (0 with prop_n 0)
+    read_ctx: np.ndarray  # ReadIndex ctx ticket (0 = none)
 
 
 class ProposeTicket:
@@ -133,7 +147,6 @@ class ProposalCoalescer:
         read_retry_rounds: int = 8,
     ):
         self.g, self.v = n_groups, n_voters
-        self.n = n_groups * n_voters
         self.max_per_round = max_entries_per_round
         # resident-entry budget: the device window holds W entries above
         # the compaction point (snap_index ~ applied - lag once
@@ -247,16 +260,16 @@ class ProposalCoalescer:
     # -- the per-round batched injection ----------------------------------
 
     def build(self, views, round_id: int):
-        """Fold the queues into one round's LocalOps columns.
+        """Fold the queues into one round's ops, one entry per lane.
 
         views: router.GroupView list (leader lane + next_index + commit
         watermark per group); next_index advances here, at assignment.
-        Returns (LocalOps | None, injections) where injections is the
+        Returns (RoundOps | None, injections) where injections is the
         [(view, [ProposeTicket, ...]), ...] the router must record before
         the round's commits can resolve. None means a zero-op round (the
         engine's cached no_ops fast path).
         """
-        prop_n = None  # allocated lazily: zero-op rounds build nothing
+        lanes, prop_n, read_ctx = [], [], []
         injections = []
         now = time.perf_counter()
         # iterate only the groups with queued/in-flight work — O(active),
@@ -269,10 +282,6 @@ class ProposalCoalescer:
             q = self.pending.get(g) or ()
             m = min(len(q), self.max_per_round, max(0, room))
             if m > 0:
-                if prop_n is None:
-                    prop_n = np.zeros((self.n,), np.int32)
-                    prop_bytes = np.zeros((self.n,), np.int32)
-                    read_ctx = np.zeros((self.n,), np.int32)
                 batch = [self.pending[g].popleft() for _ in range(m)]
                 for t in batch:
                     t.index = view.next_index
@@ -280,8 +289,6 @@ class ProposalCoalescer:
                     t.inject_t = now
                     view.next_index += 1
                 self.entries_injected += m
-                prop_n[view.leader_lane] = m
-                prop_bytes[view.leader_lane] = self.cmd_bytes
                 injections.append((view, batch))
             if (
                 self.lease_route is not None
@@ -293,18 +300,18 @@ class ProposalCoalescer:
                 # through _pick_read_ctx below
                 self.read_wait.pop(g)
             ctx = self._pick_read_ctx(g, view, round_id, now)
-            if ctx:
-                if prop_n is None:
-                    prop_n = np.zeros((self.n,), np.int32)
-                    prop_bytes = np.zeros((self.n,), np.int32)
-                    read_ctx = np.zeros((self.n,), np.int32)
-                read_ctx[view.leader_lane] = ctx
-        if prop_n is None:
+            if m > 0 or ctx:
+                lanes.append(view.leader_lane)
+                prop_n.append(m)
+                read_ctx.append(ctx)
+        if not lanes:
             return None, injections
-        from raft_tpu.ops.fused import make_local_ops
-
-        ops = make_local_ops(
-            self.n, prop_n=prop_n, prop_bytes=prop_bytes, read_ctx=read_ctx
+        prop_n = np.array(prop_n, np.int32)
+        ops = RoundOps(
+            lane=np.array(lanes, np.int32),
+            prop_n=prop_n,
+            prop_bytes=np.where(prop_n > 0, self.cmd_bytes, 0).astype(np.int32),
+            read_ctx=np.array(read_ctx, np.int32),
         )
         return ops, injections
 

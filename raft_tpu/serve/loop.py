@@ -5,8 +5,9 @@ One ServeLoop owns one FusedCluster or BlockedFusedCluster and runs the
 whole propose -> commit -> notify pipeline per device round:
 
     round += 1; admission buckets refill
-    coalescer folds the client queues into ONE LocalOps injection
-      (per block, through the scheduler's prepare_ops path)
+    coalescer folds the client queues into ONE injection: a record of
+      the lanes it touched, expanded into the per-block LocalOps on the
+      device in one dispatch (serve/inject.py)
     cluster.run(1, ops, egress=streams, auto_compact_lag=lag)
       - the push resolves the PREVIOUS round's egress bundle while this
         round computes; the CompletionRouter (the sink) advances commit
@@ -22,9 +23,10 @@ reads) and epoch resyncs (gated on observed leader changes).
 
 Measurement: each phase above is a `StepStats.span` (utils/profiling.py)
 in the loop's one bag, shared with the cluster's per-block dispatch and
-the egress streams — `serve.admission`, `serve.coalesce`,
-`serve.dispatch` (around `sched.dispatch` > `sched.enqueue`,
-`egress.resolve.wait`, `egress.sink`), `serve.drain_reads` (its
+the egress streams — `serve.admission`, `serve.coalesce` (its
+`.expand`: the op record's copy and expansion), `serve.dispatch`
+(around `sched.dispatch` > `sched.enqueue`, `egress.resolve.wait`,
+`egress.sink`), `serve.drain_reads` (its
 `.wait` / `.decode` / `.reset.wait` per block), `serve.resync` (its
 `.wait`) — each also a jax.profiler annotation on the device trace's
 clock. Tickets carry perf_counter stamps beside their round stamps, and
@@ -62,6 +64,7 @@ from raft_tpu.serve.coalescer import (
     ProposeTicket,
     ReadTicket,
 )
+from raft_tpu.serve.inject import OpExpander
 from raft_tpu.serve.kv import (
     OP_DELETE,
     OP_LEASE,
@@ -235,6 +238,14 @@ class ServeLoop:
         )
         self.coalescer.on_read_retry = lambda: self.metrics.counters.inc(
             "reads_retried"
+        )
+        # the round's touched lanes become per-block LocalOps on the
+        # device; the columns a served round never sets are each block's
+        # cached zero ops
+        self.expander = OpExpander(
+            [b._no_ops for b in cluster.blocks] if self.blocked
+            else [cluster._no_ops],
+            self.lanes_per_block,
         )
         self.router = CompletionRouter(
             self.g,
@@ -467,14 +478,16 @@ class ServeLoop:
         with self.stats.span("serve.admission"):
             self.admission.tick()
         with self.stats.span("serve.coalesce", round=self.round):
-            ops, injections = self.coalescer.build(
+            rec, injections = self.coalescer.build(
                 self.router.views, self.round
             )
             self.router.record_injections(injections)
-            if ops is not None and self.blocked:
-                # slice once, explicitly — the scheduler's identity LRU
-                # cannot hit on a fresh per-round ops object
-                ops = self.cluster.prepare_ops(ops)
+            ops = None
+            if rec is not None:
+                with self.stats.span("serve.coalesce.expand"):
+                    ops = self.expander.expand(rec)
+                if not self.blocked:
+                    ops = ops[0]
         with self.stats.span("serve.dispatch", round=self.round):
             self.cluster.run(
                 1,
@@ -548,8 +561,10 @@ class ServeLoop:
         host blocks on the device (`syncs`, one per `.wait` span),
         round-program executions enqueued (`dispatches`, one per
         `sched.enqueue` span), the egress bundles' bytes and active lanes,
-        and the coalescer's injections (`read_batches_released` is the
-        router's counter)."""
+        the coalescer's injections (`read_batches_released` is the
+        router's counter), the lanes carrying an op (`op_lanes_injected`)
+        and the expansion's capacity in use (`op_expand_capacity`, a
+        gauge)."""
         counts = self.stats.counts
         return {
             "counters": {
@@ -563,6 +578,8 @@ class ServeLoop:
                 ),
                 "entries_injected": self.coalescer.entries_injected,
                 "read_batches_injected": self.coalescer.read_batches_injected,
+                "op_lanes_injected": self.expander.lanes_injected,
+                "op_expand_capacity": self.expander.capacity,
             },
             "rounds": 0,
         }

@@ -83,6 +83,27 @@ def test_xla_round_compiles_at_the_bench_block(one_chip):
     assert "input_output_alias" in compiled.as_text()
 
 
+def test_sparse_op_expansion_compiles_at_the_served_fleet(one_chip):
+    """The served loop's op expansion (serve/inject.py) for 16 blocks of
+    196,608 lanes at capacity 128: one program whose outputs are the 16
+    blocks' three op columns, each [196,608] int32."""
+    from raft_tpu.serve.inject import _expand
+
+    k, lanes = 16, QUORUM_LANES
+    packed = jax.ShapeDtypeStruct((k, 128, 4), jnp.int32, sharding=one_chip)
+    compiled = _expand.lower(packed, lanes).compile()
+    out = compiled.out_info
+    assert len(out) == k and all(len(cols) == 3 for cols in out)
+    assert all(c.shape == (lanes,) and c.dtype == jnp.int32
+               for cols in out for c in cols)
+    # 32 KB cross to the device; the scatter writes the columns in place,
+    # with no temporary as large as one column
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == k * 128 * 4 * 4
+    assert mem.output_size_in_bytes >= k * 3 * lanes * 4
+    assert mem.temp_size_in_bytes < lanes * 4
+
+
 @pytest.mark.parametrize("kernel", ["committed", "joint_committed"])
 def test_quorum_kernel_compiles_to_mosaic(one_chip, kernel):
     match = jax.ShapeDtypeStruct((QUORUM_LANES, V), jnp.int32,

@@ -112,6 +112,13 @@ def _cmd(seq, key="k"):
     return Command(OP_PUT, "t", 1, seq, key, seq)
 
 
+def _at(rec, field, lane=0):
+    """The RoundOps record's `field` for `lane` (0 when it has no entry)."""
+    hit = np.nonzero(rec.lane == lane)[0]
+    assert hit.size <= 1, "one entry per lane"
+    return int(getattr(rec, field)[hit[0]]) if hit.size else 0
+
+
 def test_coalescer_caps_per_round_batch_at_max_msg_entries():
     co = ProposalCoalescer(
         1, 3, max_entries_per_round=4, log_window=64, compact_lag=16,
@@ -120,19 +127,21 @@ def test_coalescer_caps_per_round_batch_at_max_msg_entries():
     for i in range(10):
         assert co.enqueue(ProposeTicket(_cmd(i + 1), 0, 0)) is None
     views = [_View(leader_lane=0)]
-    ops, inj = co.build(views, round_id=1)
-    assert ops is not None
+    rec, inj = co.build(views, round_id=1)
+    assert rec is not None
     # the kernel clamps prop_n at E — the host must never exceed it
-    assert int(np.asarray(ops.prop_n)[0]) == 4
+    assert _at(rec, "prop_n") == 4
+    assert _at(rec, "prop_bytes") == co.cmd_bytes and _at(rec, "read_ctx") == 0
     (view, batch), = inj
     assert [t.index for t in batch] == [1, 2, 3, 4]
     assert views[0].next_index == 5
-    ops, _ = co.build(views, round_id=2)
-    assert int(np.asarray(ops.prop_n)[0]) == 4
-    ops, _ = co.build(views, round_id=3)
-    assert int(np.asarray(ops.prop_n)[0]) == 2  # tail
-    ops, inj = co.build(views, round_id=4)
-    assert ops is None and inj == []  # idle round builds nothing
+    rec, _ = co.build(views, round_id=2)
+    assert _at(rec, "prop_n") == 4
+    rec, _ = co.build(views, round_id=3)
+    assert _at(rec, "prop_n") == 2  # tail
+    assert rec.lane.tolist() == [0]  # only the touched lane is sent
+    rec, inj = co.build(views, round_id=4)
+    assert rec is None and inj == []  # idle round builds nothing
 
 
 def test_coalescer_window_budget_backpressure():
@@ -144,12 +153,12 @@ def test_coalescer_window_budget_backpressure():
     for i in range(20):
         co.enqueue(ProposeTicket(_cmd(i + 1), 0, 0))
     views = [_View(leader_lane=0)]
-    n1 = int(np.asarray(co.build(views, 1)[0].prop_n)[0])
-    n2 = int(np.asarray(co.build(views, 2)[0].prop_n)[0])
+    n1 = _at(co.build(views, 1)[0], "prop_n")
+    n2 = _at(co.build(views, 2)[0], "prop_n")
     assert n1 + n2 == 10  # stalls at the budget while watermark is stuck
     assert co.build(views, 3)[0] is None
     views[0].watermark = 10  # commits applied -> window drains
-    n3 = int(np.asarray(co.build(views, 4)[0].prop_n)[0])
+    n3 = _at(co.build(views, 4)[0], "prop_n")
     assert n3 == 8  # E-capped resumption
 
 
@@ -171,18 +180,119 @@ def test_coalescer_reads_share_one_ctx_per_group_round():
     )
     for i in range(5):
         co.enqueue_read(ReadTicket(1, 0, f"k{i}", 0))
-    ops, _ = co.build([_View(leader_lane=0)], 1)
-    ctx = int(np.asarray(ops.read_ctx)[0])
+    rec, _ = co.build([_View(leader_lane=0)], 1)
+    ctx = _at(rec, "read_ctx")
     assert ctx > 0
+    assert _at(rec, "prop_n") == 0 and _at(rec, "prop_bytes") == 0
     assert co.outstanding_reads == 1  # ONE batch carries all five
     assert len(co.read_batches[ctx].tickets) == 5
     # a due retry re-injects the SAME ctx (idempotent release contract)
     retried = []
     co.on_read_retry = lambda: retried.append(1)
-    ops, _ = co.build([_View(leader_lane=0)], 5)
-    assert int(np.asarray(ops.read_ctx)[0]) == ctx
+    rec, _ = co.build([_View(leader_lane=0)], 5)
+    assert _at(rec, "read_ctx") == ctx
     assert retried == [1]
     assert co.take_batch(ctx) is not None and co.take_batch(ctx) is None
+
+
+def test_coalescer_proposal_and_read_on_one_lane_are_one_entry():
+    co = ProposalCoalescer(
+        2, 3, max_entries_per_round=8, log_window=64, compact_lag=16,
+        max_read_batches=2,
+    )
+    co.enqueue(ProposeTicket(_cmd(1), 1, 0))
+    co.enqueue(ProposeTicket(_cmd(2), 1, 0))
+    co.enqueue_read(ReadTicket(1, 1, "k", 0))
+    co.enqueue_read(ReadTicket(1, 0, "k", 0))
+    rec, _ = co.build([_View(leader_lane=2), _View(leader_lane=4)], 1)
+    assert rec.lane.tolist() == [2, 4]  # group order, one entry per lane
+    assert [_at(rec, "prop_n", 4), _at(rec, "prop_bytes", 4)] == [
+        2, co.cmd_bytes,
+    ]
+    assert _at(rec, "read_ctx", 4) > 0 and _at(rec, "read_ctx", 2) > 0
+    assert _at(rec, "prop_n", 2) == 0 and _at(rec, "prop_bytes", 2) == 0
+    assert all(c.dtype == np.int32 for c in rec)
+
+
+# -- sparse op injection (serve/inject.py) ----------------------------------
+
+_INJ_GROUPS = 64  # groups per block: 192 lanes, so a block can pass C=64
+
+
+def _inj_lanes(kind, k, lpb):
+    """Global lanes of one lane set over K blocks of `lpb` lanes."""
+    last = (k - 1) * lpb
+    return {
+        "empty": [],
+        "one_lane": [last + 5],
+        "prop_and_read_same_lane": [last + 7],
+        "every_lane_of_one_block": list(range(last, last + lpb)),
+        # 65 lanes in block 0 pass the 64 bucket; one lane in every other
+        "crosses_a_bucket": list(range(0, 130, 2))
+        + [i * lpb + 1 for i in range(1, k)],
+    }[kind]
+
+
+@pytest.mark.parametrize("k", [1, 2, 4])
+@pytest.mark.parametrize(
+    "kind",
+    ["empty", "one_lane", "prop_and_read_same_lane",
+     "every_lane_of_one_block", "crosses_a_bucket"],
+)
+def test_sparse_expansion_matches_dense_ops(k, kind):
+    """The expanded per-block LocalOps equal make_local_ops + prepare_ops
+    on the same lanes bit for bit (values, dtype, shape, placement), pad
+    entries dropped, and the expansion compiles nothing once the expander
+    is made."""
+    from raft_tpu.ops.fused import make_local_ops, no_ops
+    from raft_tpu.scheduler import BlockPlan
+    from raft_tpu.serve.coalescer import RoundOps
+    from raft_tpu.serve.inject import (
+        OpExpander, _expand, capacity, pack_blocks,
+    )
+
+    plan = BlockPlan(k * _INJ_GROUPS, 3, _INJ_GROUPS)
+    lpb = plan.lanes_per_block
+    lanes = np.array(_inj_lanes(kind, k, lpb), np.int32)
+    rng = np.random.default_rng([k, len(lanes)])
+    prop_n = rng.integers(0, 9, lanes.size).astype(np.int32)
+    read_ctx = np.where(
+        rng.random(lanes.size) < 0.5, rng.integers(1, 1 << 30, lanes.size), 0
+    ).astype(np.int32)
+    if kind == "prop_and_read_same_lane":
+        prop_n[:], read_ctx[:] = 3, 12345
+    prop_n[(prop_n == 0) & (read_ctx == 0)] = 1  # every entry carries an op
+    rec = RoundOps(
+        lanes, prop_n, np.where(prop_n > 0, 64, 0).astype(np.int32), read_ctx
+    )
+    ex = OpExpander([no_ops(lpb) for _ in range(k)], lpb)
+    compiled = _expand._cache_size()
+    got = ex.expand(rec)
+    assert _expand._cache_size() == compiled  # made with every capacity
+    per_block = np.bincount(lanes // lpb, minlength=k)
+    want_c = capacity(int(per_block.max()), lpb)
+    assert ex.capacity == want_c and ex.lanes_injected == lanes.size
+    assert want_c == {
+        "every_lane_of_one_block": lpb, "crosses_a_bucket": 128,
+    }.get(kind, 64)
+    packed = pack_blocks(rec, k, lpb)
+    assert packed.shape == (k, want_c, 4) and packed.dtype == np.int32
+    # every slot past a block's entries is a pad the scatter must drop
+    assert ((packed[:, :, 0] == lpb).sum(axis=1) == want_c - per_block).all()
+    cols = {
+        f: dict(zip(lanes.tolist(), getattr(rec, f).tolist()))
+        for f in ("prop_n", "prop_bytes", "read_ctx")
+    }
+    want = plan.prepare_ops(make_local_ops(k * lpb, **cols))
+    assert len(got) == k
+    for g, w in zip(got, want):
+        for f in g._fields:
+            a, b = getattr(g, f), getattr(w, f)
+            assert (a.shape, a.dtype, a.weak_type) == (
+                b.shape, b.dtype, b.weak_type
+            ), f
+            assert (a.sharding, a.committed) == (b.sharding, b.committed), f
+            assert np.array_equal(np.asarray(a), np.asarray(b)), f
 
 
 def test_delta_bundle_rs_count_keeps_lane_active():
@@ -500,6 +610,58 @@ def test_blocked_round_spans_counters_and_stage_stamps(blocked_loop):
     assert f"raft_tpu_serve_syncs_total {cnt['syncs']}" in text
     assert 'raft_tpu_serve_get_readindex_ms_bucket{le="+Inf"}' in text
     assert "raft_tpu_serve_put_commit_ms_count" in text
+
+
+def test_blocked_sparse_rounds_match_dense_ops():
+    """Blocked ServeLoop rounds fed by the sparse expansion reach the same
+    digest and device columns as twins fed make_local_ops + prepare_ops,
+    count `op_lanes_injected` as the lanes that carried an op, and compile
+    no extra variant of the round program."""
+    from raft_tpu.ops.fused import _fused_rounds_jit, make_local_ops
+    from raft_tpu.scheduler import BlockedFusedCluster
+
+    def serve(dense):
+        sl = ServeLoop(BlockedFusedCluster(4, 3, block_groups=2, seed=13))
+        sl.bootstrap()
+        sparse, touched = sl.expander.expand, []
+
+        def expand(rec):
+            carry = (rec.prop_n > 0) | (rec.read_ctx > 0)
+            touched.append(len(set(rec.lane[carry].tolist())))
+            if not dense:
+                return sparse(rec)
+            cols = {
+                f: dict(zip(rec.lane.tolist(), getattr(rec, f).tolist()))
+                for f in ("prop_n", "prop_bytes", "read_ctx")
+            }
+            return sl.cluster.prepare_ops(make_local_ops(sl.n, **cols))
+
+        sl.expander.expand = expand
+        ss = [sl.open_session(f"sx{i}") for i in range(6)]
+        assert len({s.group for s in ss}) >= 2  # spans blocks
+        for i in range(3):
+            for s in ss:
+                assert sl.put(s, f"{s.tenant}/{i}", i)
+            assert sl.get(ss[i], f"{ss[i].tenant}/0")  # reads beside puts
+            sl.step()
+        assert sl.drain(300)
+        cols = sl.cluster.state_columns("term", "committed", "last")
+        return sl, touched, cols
+
+    dense, _, dense_cols = serve(dense=True)
+    variants = _fused_rounds_jit._cache_size()
+    sl, touched, cols = serve(dense=False)
+    assert _fused_rounds_jit._cache_size() == variants
+    assert sl.round == dense.round
+    assert sl.digest() == dense.digest() == sl.twin_digest()
+    for name in cols:
+        assert np.array_equal(cols[name], dense_cols[name]), name
+    c = sl.metrics_snapshot()["counters"]
+    assert sum(touched) > 0 and c["op_lanes_injected"] == sum(touched)
+    assert c["op_expand_capacity"] == 6  # min(64, lanes per block)
+    # one child span per round that injected anything
+    assert sl.stats.counts["coalesce.expand"] == len(touched)
+    assert sl.stats.counts["coalesce.expand"] < sl.stats.counts["coalesce"]
 
 
 def test_blocked_no_leader_gate_before_bootstrap():
