@@ -40,7 +40,7 @@ _JIT_NAME_RE = re.compile(r"jit\(([^)]*)\)")
 ENTRY_JIT_NAMES = {
     "round.xla": ("fused_rounds",),
     "round.pallas": ("pallas_rounds",),
-    "sharded.step.xla": ("stepper",),
+    "sharded.step.xla": ("shard_mapped_rounds",),
     "quorum.pallas": ("joint_committed_pallas", "committed_pallas"),
     "quorum.xla": ("joint_committed",),
     "egress.ready_bundle": ("ready_bundle",),

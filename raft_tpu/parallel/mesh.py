@@ -117,18 +117,21 @@ class MeshBlockedCluster:
 
             if pgmod.paged_enabled():
                 pgmod.validate_page_plan(shape, self.lanes_per_block)
+        # one span bag for the driver and its blocks (scheduler contract);
+        # the build records `mesh.build` per block
+        self.stats = StepStats()
         # the scheduler's block-seed scheme: trajectories match an
         # equal-total-groups BlockedFusedCluster bit for bit
-        self.blocks = [
-            ShardedFusedCluster(
-                self.block_groups, n_voters, devices=devices,
-                seed=seed + 7919 * i, straddle=straddle, shape=shape, **cfg
-            )
-            for i in range(self.k)
-        ]
+        self.blocks = []
+        for i in range(self.k):
+            with self.stats.span("mesh.build", block=i):
+                self.blocks.append(ShardedFusedCluster(
+                    self.block_groups, n_voters, devices=devices,
+                    seed=seed + 7919 * i, straddle=straddle, shape=shape,
+                    **cfg
+                ))
         self.lanes_per_shard = self.blocks[0].lanes_per_shard
-        # one span bag for the driver and its blocks (scheduler contract)
-        self.use_stats(StepStats())
+        self.use_stats(self.stats)
         # hot/cold tiering (RAFT_TPU_TIER): per-block engines re-attached
         # with their contiguous slice of the logical id space (the
         # scheduler's exact partition), each keeping the sharded driver's

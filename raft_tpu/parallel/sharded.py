@@ -550,8 +550,15 @@ class ShardedFusedCluster:
         has_tr, has_pg = tr is not None, pg is not None
         extras = [x for x in (met, ch, tr, pg) if x is not None]
 
+        # the program closes over these and not over self, so a dropped
+        # cluster is freed at once and not left to the cycle collector
+        v, spec, lanes_per_shard = self.v, self._spec, self.lanes_per_shard
+        paged_inkernel = self.inner._paged_inkernel
 
-        def stepper(st, f, o, m, *ex):
+        # named for the device trace: its executions show on the `XLA
+        # Modules` line as jit_shard_mapped_rounds (benchmark/metrics/
+        # mesh.round.device_ms.py), apart from the one-chip fused_rounds
+        def shard_mapped_rounds(st, f, o, m, *ex):
             mt = ex[0] if has_met else None
             c = ex[int(has_met)] if has_ch else None
             t = ex[int(has_met) + int(has_ch)] if has_tr else None
@@ -577,30 +584,30 @@ class ShardedFusedCluster:
                 )
                 lane_off = (
                     jax.lax.axis_index("groups")
-                    * jnp.int32(self.lanes_per_shard)
+                    * jnp.int32(lanes_per_shard)
                 )
             if engine == "pallas":
                 res = plr.pallas_rounds(
                     st, f, o, m,
-                    v=self.v, tile_lanes=tile, n_rounds=rounds,
+                    v=v, tile_lanes=tile, n_rounds=rounds,
                     rounds_per_call=rpc,
                     do_tick=do_tick, auto_propose=auto_propose,
                     auto_compact_lag=auto_compact_lag,
                     interpret=interp, metrics=mt, chaos=c,
                     trace=t_loc, trace_lane_offset=lane_off,
                     paged=p_in,
-                    paged_inkernel=self.inner._paged_inkernel,
+                    paged_inkernel=paged_inkernel,
                 )
             else:
                 res = fused_rounds(
                     st, f, o, m,
-                    v=self.v, n_rounds=rounds, do_tick=do_tick,
+                    v=v, n_rounds=rounds, do_tick=do_tick,
                     auto_propose=auto_propose,
                     auto_compact_lag=auto_compact_lag,
-                    straddle=self._spec, metrics=mt, chaos=c,
+                    straddle=spec, metrics=mt, chaos=c,
                     trace=t_loc, trace_lane_offset=lane_off,
                     paged=p_in,
-                    paged_inkernel=self.inner._paged_inkernel,
+                    paged_inkernel=paged_inkernel,
                 )
             out = [res[0], res[1]]
             j = 2
@@ -707,7 +714,7 @@ class ShardedFusedCluster:
             in_specs.append(pg_specs)
             out_specs.append(pg_specs)
         fn = shard_map(
-            stepper,
+            shard_mapped_rounds,
             mesh=self.mesh,
             in_specs=tuple(in_specs),
             out_specs=tuple(out_specs),

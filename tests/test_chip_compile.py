@@ -140,8 +140,8 @@ def test_pallas_round_is_refused_by_mosaic(one_chip, monkeypatch):
 def test_sharded_stepper_compiles_on_a_2x2_mesh(topo, one_chip):
     """The shard_map stepper MeshBlockedCluster dispatches per block,
     lowered over the four described chips with NamedSharding inputs:
-    every lane column splits over the mesh, and the metrics psum is the
-    only collective."""
+    every lane column splits over the mesh, the metrics psum is the only
+    collective, and the program carries the name the benchmark reads."""
     from raft_tpu.parallel.sharded import ShardedFusedCluster
 
     g = 4096
@@ -164,3 +164,21 @@ def test_sharded_stepper_compiles_on_a_2x2_mesh(topo, one_chip):
     text = compiled.as_text()
     assert "all-reduce" in text
     assert "all-to-all" not in text and "all-gather" not in text
+    # the module is named for the trace's `XLA Modules` line, as the
+    # benchmark's mesh.round.device_ms reads it, apart from the one-chip
+    # round's `fused_rounds` (round.device_ms)
+    program = _mesh_round_program()
+    assert text.startswith(f"HloModule jit_{program},")
+    assert "fused_rounds" not in program
+
+
+def _mesh_round_program() -> str:
+    """The program name benchmark/metrics/mesh.round.device_ms.py matches."""
+    import importlib.util
+
+    path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                        "benchmark", "metrics", "mesh.round.device_ms.py")
+    spec = importlib.util.spec_from_file_location("mesh_round_ms", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.PROGRAM

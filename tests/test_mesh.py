@@ -123,6 +123,14 @@ def test_mesh_matches_blocked_bitwise(monkeypatch, devices):
     assert mesh.leader_count() == g
     np.testing.assert_array_equal(mesh.leader_lanes(), mono.leader_lanes())
     assert mesh.total_committed() == mono.total_committed()
+    _assert_build_spans(mesh)
+
+
+def _assert_build_spans(mesh):
+    """The build records one `mesh.build` span per block."""
+    spans = mesh.stats.as_dict()
+    assert spans["mesh.build"]["count"] == mesh.k
+    assert spans["mesh.build"]["seconds"] > 0
 
 
 def test_mesh_k1_matches_blocked_bitwise(monkeypatch, devices):
@@ -141,6 +149,7 @@ def test_mesh_k1_matches_blocked_bitwise(monkeypatch, devices):
     )
     assert mesh.k == 1
     assert _digest(mesh) == _digest(mono)
+    _assert_build_spans(mesh)
 
 
 def test_mesh_stream_payloads_match_blocked(monkeypatch, devices):
@@ -286,3 +295,28 @@ def test_serve_loop_on_mesh_round_trip(monkeypatch, devices):
     assert sl.drain(300)
     for s, rt in zip(ss, rts):
         assert rt.done and rt.value == f"{s.tenant}-3"
+
+
+# -- the mesh's build ------------------------------------------------------
+
+
+def test_mesh_block_placement_matches_the_whole_build(monkeypatch, devices):
+    """A block placed on the mesh splits every lane column over the
+    devices (each holds its slice only) and equals a FusedCluster built
+    whole on one device with the same seed, leaf for leaf."""
+    from raft_tpu.ops.fused import FusedCluster
+    from raft_tpu.parallel.sharded import ShardedFusedCluster
+
+    _set_env(monkeypatch)
+    g, v = 16, 3
+    sh = ShardedFusedCluster(g, v, devices=devices, seed=11,
+                             shape=_block_shape(g, v))
+    whole = FusedCluster(g, v, seed=11, shape=_block_shape(g, v))
+    for placed, built in ((sh.inner.state, whole.state),
+                          (sh.inner.fab, whole.fab)):
+        for a, b in zip(jax.tree.leaves(placed), jax.tree.leaves(built)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            if a.ndim and a.shape[0] == g * v:
+                assert a.sharding == sh.lane_sharding
+                assert {s.data.shape[0] for s in a.addressable_shards} == {
+                    g * v // len(devices)}
