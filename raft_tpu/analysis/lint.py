@@ -93,6 +93,7 @@ BENCH_ALLOW = {
     "benches/dispatch_ab.py": set(),
     "benches/egress_ab.py": set(),
     "benches/fabric_ab.py": {"child"},
+    "benches/hlo_sources.py": set(),
     "benches/latency_probe.py": {"measure", "measure_blocked"},
     "benches/lease_ab.py": {"child"},
     "benches/metrics_smoke.py": set(),

@@ -158,6 +158,10 @@ class Shape:
             if not 1 <= getattr(self, f) <= 127:
                 raise ValueError(f"{f} must be in 1..127 (int8 carry diet; "
                                  "inbox sizing assumes at least 1)")
+        # a message never carries more entries than the window holds, and
+        # the log's range ops address a run inside one window
+        if self.max_msg_entries > self.log_window:
+            raise ValueError("max_msg_entries must be <= log_window")
         # the diet-v2 packed carry (state.pack_state) stores the per-peer
         # bool masks as one bitset word per lane and the rebased index
         # columns as uint16: V must fit one 32-bit word, and the window

@@ -141,10 +141,10 @@ def last_term(state: RaftState):
 
 
 def terms_range(state: RaftState, start, e: int):
-    """term_at for the contiguous indexes start..start+e-1 ([N] -> [N, e]) —
-    one one-hot + e rolls instead of an [N, e, W] gather tensor."""
+    """term_at for the contiguous indexes start..start+e-1 ([N] -> [N, e]),
+    read by slot offset from `start` instead of an [N, e, W] gather."""
     idx = start[:, None] + jnp.arange(e, dtype=I32)[None, :]
-    raw = oh.gather_range(state.log_term, slot_of(state, start), e)
+    (raw,) = oh.gather_range_multi([state.log_term], slot_of(state, start), e)
     return _mask_terms(state, idx, raw)
 
 
@@ -223,16 +223,13 @@ def append(
     state = _err(state, overflow, ERR_WINDOW_OVERFLOW)
     ok = act & (prev_index >= state.committed) & ~overflow
 
-    write = ok[:, None] & (jnp.arange(e, dtype=I32)[None, :] < n_ents[:, None])
-    slot0 = slot_of(state, prev_index + 1)
-
-    # contiguous circular scatter of [N, E] vals into [N, W]; the three
-    # columns share one set of rolled one-hot masks
+    # contiguous circular scatter of the [N, E] prefix into [N, W], the
+    # three columns at one set of slot offsets
     new_term, new_type, new_bytes = oh.scatter_range_set_multi(
         [state.log_term, state.log_type, state.log_bytes],
-        slot0,
+        slot_of(state, prev_index + 1),
         [ent_term, ent_type, ent_bytes],
-        write,
+        jnp.where(ok, n_ents, 0),
     )
 
     new_last = jnp.where(ok, prev_index + n_ents, state.last)
@@ -284,7 +281,7 @@ def maybe_append(
     e = ent_term.shape[-1]
 
     # contiguous in the source; wrapped reads land only in slots the
-    # n_keep write mask excludes. One shared rolled-mask set for the triple.
+    # n_keep write mask excludes. One set of slot offsets for the triple.
     sh_term, sh_type, sh_bytes = oh.gather_range_multi(
         [ent_term, ent_type, ent_bytes], shift, e
     )

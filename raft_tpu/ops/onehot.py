@@ -6,6 +6,9 @@ compare+select+reduce chains run at full VPU rate and fuse. Every index domain
 in this engine is small and static (log window W, peer slots V<=8, entries per
 message E<=8, inflight ring F<=8, read slots R<=4), so indexed access is
 re-expressed as one-hot arithmetic: build `idx == iota` masks and reduce.
+A contiguous circular range of the log window is addressed by each slot's
+offset from the range's start (`offsets`), computed once per range, not by
+a rolled one-hot mask per entry.
 This is the "masked lane-wise" style SURVEY §2.3/§7 prescribes; sorting uses a
 fixed odd-even transposition network (quorum/majority.go:126-172's sort of
 <=7 voters needs no general sort, per SURVEY §7 hard-parts).
@@ -73,48 +76,71 @@ def scatter_set(col, idx, vals, mask):
     return jnp.where(hit, val, col)
 
 
-def gather_range(col, start, e: int):
-    """Contiguous circular gather: out[..., k] = col[..., (start+k) mod W]
-    for k in [0, e). col [B..., W]; start [B...] (or with extra leading-dim
-    broadcast like `gather`). One one-hot + e static rolls — peak memory is
-    one [..., W] mask instead of the [..., e, W] tensor a general gather
-    needs (the difference between fitting in HBM and spilling at 1M lanes)."""
-    w = col.shape[-1]
-    if col.dtype == jnp.bool_:
-        return gather_range(col.astype(I32), start, e).astype(jnp.bool_)
-    oh0 = onehot(start % w, w)  # [..., W]
-    extra = oh0.ndim - col.ndim
-    c = col.reshape(col.shape[:-1] + (1,) * extra + (w,))
-    # k == 0 skips the roll: jnp.roll(x, 0) lowers to a concat with an
-    # empty slice, which Mosaic (Pallas TPU) rejects
-    outs = [
-        jnp.sum(
-            jnp.where(oh0 if k == 0 else jnp.roll(oh0, k, axis=-1), c, 0),
-            axis=-1,
-        )
-        for k in range(e)
-    ]
-    return jnp.stack(outs, axis=-1)
+def offsets(start, w: int):
+    """[...] slot -> [..., W]: each slot's offset from a run that starts at
+    slot `start`, (w - start) mod W. A contiguous range of the circular
+    window is addressed by this one compare-ready column instead of a
+    rolled one-hot mask per entry."""
+    s = jnp.arange(w, dtype=I32) - start[..., None]
+    return s & (w - 1) if w & (w - 1) == 0 else s % w
+
+
+def _rotate(x, r):
+    """out[..., j] = x[..., (j - r) mod K] along the small last axis (K a
+    power of two, r [...] in [0, K)): log2(K) static rolls of the [.., K]
+    values, each kept where r has that bit. Never rolls a [.., W] array:
+    XLA materializes every stage of such a barrel."""
+    k = x.shape[-1]
+    b = 1
+    while b < k:
+        x = jnp.where(((r & b) != 0)[..., None], jnp.roll(x, b, axis=-1), x)
+        b <<= 1
+    return x
+
+
+def place(vals, start, w: int):
+    """[..., K] run -> [..., W] in which each slot whose offset from `start`
+    is below K holds its entry, vals[..., (slot - start) mod W] (K <= W, W a
+    power of two); the other slots are don't-care.
+
+    A window of a lane or more (W >= 128) and a run of 5 or more entries:
+    the run, padded with zeros to a power of two P (which divides W), is
+    rotated by start mod P on its small axis, then tiled W/P times, one
+    select per slot. Otherwise K - 1 compares of the slot offsets, which
+    the compiler's cost model puts below the tile's relayout there."""
+    k = vals.shape[-1]
+    p = 1 << (k - 1).bit_length()
+    if w < 128 or p < 8:
+        kw = offsets(start, w)
+        out = vals[..., :1]
+        for j in range(1, k):
+            out = jnp.where(kw == j, vals[..., j : j + 1], out)
+        return jnp.broadcast_to(out, kw.shape)
+    if p > k:
+        pad = jnp.zeros(vals.shape[:-1] + (p - k,), vals.dtype)
+        vals = jnp.concatenate([vals, pad], axis=-1)
+    rot = _rotate(vals, start & (p - 1))
+    return jnp.tile(rot, (1,) * (rot.ndim - 1) + (w // p,))
 
 
 def gather_range_multi(cols, start, e: int):
-    """gather_range over several same-shape columns at the SAME start:
-    builds the one-hot + rolled masks once and reads them once per column
-    (the log window's (term, type, bytes) triple always moves together —
-    three separate gathers made XLA materialize and re-read the [.., W]
-    masks three times, ~6% of the fused round's HBM traffic)."""
+    """Contiguous circular gather over several same-shape columns at the
+    SAME start: out[..., k] = col[..., (start + k) mod W] for k in [0, e).
+    col [B..., W]; start [B...] or with extra dims that broadcast, as in
+    `gather`. Each slot's offset from the start is computed once, and each
+    output entry is one compare-and-reduce over it, shared by the columns
+    (the log window's (term, type, bytes) triple always moves together)."""
     w = cols[0].shape[-1]
-    oh0 = onehot(start % w, w)
-    rolled = [oh0 if k == 0 else jnp.roll(oh0, k, axis=-1) for k in range(e)]
+    kw = offsets(start, w)
     outs = []
     for col in cols:
         as_bool = col.dtype == jnp.bool_
         if as_bool:
             col = col.astype(I32)
-        extra = oh0.ndim - col.ndim
+        extra = kw.ndim - col.ndim
         c = col.reshape(col.shape[:-1] + (1,) * extra + (w,))
         out = jnp.stack(
-            [jnp.sum(jnp.where(r, c, 0), axis=-1) for r in rolled],
+            [jnp.sum(jnp.where(kw == k % w, c, 0), axis=-1) for k in range(e)],
             axis=-1,
         )
         outs.append(out.astype(jnp.bool_) if as_bool else out)
@@ -122,41 +148,29 @@ def gather_range_multi(cols, start, e: int):
 
 
 def scatter_range_set_multi(cols, start, vals_list, mask):
-    """scatter_range_set over several same-shape columns at the SAME
-    start/mask, sharing the rolled one-hot masks (see gather_range_multi)."""
+    """Contiguous circular scatter over several same-shape columns at the
+    SAME start: col[..., (start + k) mod W] = vals[..., k] where the entry
+    is written. cols [..., W] (W a power of two); start [...]; vals
+    [..., K], K <= W. `mask` is a [..., K] bool, or an integer count n [...]
+    for the prefix k < n, which every append writes (one compare a slot).
+
+    Each slot's offset from the start is computed once, the run is placed
+    by `place` (padded with masked-off entries where K is not a power of
+    two), and each column is read once and written once."""
     w = cols[0].shape[-1]
-    k_count = vals_list[0].shape[-1]
-    oh0 = onehot(start % w, w)
-    ohks = []
-    for k in range(k_count):
-        rolled = oh0 if k == 0 else jnp.roll(oh0, k, axis=-1)
-        ohks.append(rolled & mask[..., k : k + 1])
-    outs = []
-    for col, vals in zip(cols, vals_list):
-        hit = jnp.zeros(col.shape, dtype=jnp.bool_)
-        acc = jnp.zeros(col.shape, dtype=col.dtype)
-        for k, ohk in enumerate(ohks):
-            hit = hit | ohk
-            acc = jnp.where(ohk, vals[..., k : k + 1], acc)
-        outs.append(jnp.where(hit, acc, col))
-    return outs
-
-
-def scatter_range_set(col, start, vals, mask):
-    """Contiguous circular scatter: col[..., (start+k) mod W] = vals[..., k]
-    where mask[..., k]. col [..., W]; start [...]; vals/mask [..., K].
-    Same roll trick as gather_range: peak memory stays [..., W]."""
-    w = col.shape[-1]
-    k_count = vals.shape[-1]
-    oh0 = onehot(start % w, w)
-    hit = jnp.zeros(col.shape, dtype=jnp.bool_)
-    acc = jnp.zeros(col.shape, dtype=col.dtype)
-    for k in range(k_count):
-        rolled = oh0 if k == 0 else jnp.roll(oh0, k, axis=-1)
-        ohk = rolled & mask[..., k : k + 1]
-        hit = hit | ohk
-        acc = jnp.where(ohk, vals[..., k : k + 1], acc)
-    return jnp.where(hit, acc, col)
+    k = vals_list[0].shape[-1]
+    if k > w:
+        raise ValueError(f"a run of {k} entries does not fit a window of {w}")
+    count = None if mask.dtype == jnp.bool_ else mask
+    kw = offsets(start, w)
+    if count is not None:
+        hit = kw < jnp.minimum(count, k)[..., None]
+    else:
+        hit = place(mask, start, w) & (kw < k)
+    return [
+        jnp.where(hit, place(v, start, w), col)
+        for col, v in zip(cols, vals_list)
+    ]
 
 
 def sort_last(x, valid=None, pad=-1):

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from benches.hlo_sources import window_arrays
 from raft_tpu.config import Shape
 from raft_tpu.ops import fused
 from raft_tpu.ops import pallas_round as plr
@@ -80,7 +81,13 @@ def test_xla_round_compiles_at_the_bench_block(one_chip):
     mem = compiled.memory_analysis()
     resident = 16 * mem.argument_size_in_bytes
     assert resident + mem.temp_size_in_bytes < HBM_BYTES
-    assert "input_output_alias" in compiled.as_text()
+    text = compiled.as_text()
+    assert "input_output_alias" in text
+    # the log window's range ops address slots by their offset from the
+    # run's start: no rolled one-hot [lanes, W] mask per entry is written
+    # between the round loop's fusions (10 with them, 5 without)
+    n_window, _ = window_arrays(text, BENCH_BLOCK * V, 16)
+    assert n_window <= 6
 
 
 def test_sparse_op_expansion_compiles_at_the_served_fleet(one_chip):
